@@ -21,6 +21,7 @@ import jax
 
 from repro.core import mr_join as mj
 from repro.core.planner import plan_bgp
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sparql import lubm
 from repro.sparql.baseline import (hash_join, nested_loop_join,
                                    partitioned_hash_join)
@@ -104,6 +105,7 @@ def bench(scale: int = 3, seed: int = 0) -> list[dict]:
 
 
 def main() -> None:
+    enable_compile_cache()
     print("# Table 2 reproduction: join time (ms), LUBM scale=3")
     print("query,inputs,n_result,gStore_ms,gStoreD_ms,MapSQ_ms,nested_ms,"
           "SpeedUp_g,SpeedUp_D")

@@ -35,12 +35,16 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import time
 
+import jax
+
+from repro.core import compat
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sparql import lubm
-from repro.sparql.engine import QueryEngine
+from repro.sparql.engine import QueryEngine, ShardedQueryEngine
+from repro.sparql.sharded_store import shard_store
 
 # operator-coverage shapes: device-side FILTER masks, OPTIONAL left joins
 # with UNBOUND padding, a LIMIT slice, and a UNION concat
@@ -129,12 +133,16 @@ def bench_batched(store, repeats: int) -> list[dict]:
 
 
 # sharded-vs-single device counts for the D1 shape (1 = the no-sharding
-# baseline, 4 = the scaling point — both forced host devices, CPU-safe)
+# baseline, 4 = the scaling point). Both meshes are built in THIS process
+# from jax.devices(): on the chip one process holds every device; on the
+# CPU, main() forces 4 host devices before jax initialises.
 D1_DEVICE_COUNTS = (1, 4)
-# the join-heavy D1 subset; MUST mirror bench_sharded_prog.D1_QUERIES
-# (the prog can't be imported here — its module body parses sys.argv and
-# forces the device count before importing jax)
+# D1: join-heavy shapes (the per-shard bucket-shrink claim)
 D1_QUERIES = ("Q2", "Q7", "Q9", "J1")
+# D2: subject-star shapes — every join key is the shared subject variable,
+# so the subject-hash partitioned scans are ALREADY aligned and the
+# lowering elides every shuffle (0 emitted collectives, asserted below)
+STAR_QUERIES = ("Q1", "Q4")
 # the 4-device wall-time win needs enough data for the smaller per-shard
 # sorts to amortise the mesh dispatch overhead (on a single-core host the
 # whole win IS the O(n log^2 n) bitonic work reduction); below this scale
@@ -142,15 +150,63 @@ D1_QUERIES = ("Q2", "Q7", "Q9", "J1")
 D2_WALL_WIN_MIN_SCALE = 8
 
 
+def _best_of(fn, repeat: int) -> float:
+    """Best-of-repeat wall time: the min is the noise-robust statistic on
+    a shared host (a load spike inflates the mean but not the min)."""
+    best = float("inf")
+    for _ in range(repeat):
+        t0 = time.perf_counter()
+        fn()
+        best = min(best, time.perf_counter() - t0)
+    return best
+
+
+def _sharded_records(store, n_dev: int, repeats: int) -> list[dict]:
+    """Warm per-query latency of the single-device engine and a sharded
+    engine over the first `n_dev` devices, plus bucket and shuffle
+    counts, for every D-series query."""
+    if jax.device_count() < n_dev:
+        raise RuntimeError(
+            f"the D-series needs {n_dev} devices, jax sees "
+            f"{jax.device_count()}"
+        )
+    mesh = compat.make_mesh((n_dev,), ("shards",),
+                            devices=jax.devices()[:n_dev])
+    single = QueryEngine(store)
+    sharded = ShardedQueryEngine(shard_store(store, n_dev), mesh=mesh)
+    queries = {**lubm.QUERIES, **lubm.J_QUERIES}
+    records = []
+    for name in D1_QUERIES + STAR_QUERIES:
+        pq_si = single.prepare(queries[name])
+        pq_sh = sharded.prepare(queries[name])
+        rows_si, rows_sh = pq_si.run(), pq_sh.run()
+        assert len(rows_si) == len(rows_sh), (name, len(rows_si),
+                                              len(rows_sh))
+        warm_si, warm_sh = pq_si.run(), pq_sh.run()
+        assert warm_sh.stats.n_dispatches == 1 and (
+            warm_sh.stats.n_compiles == 0
+        ), (name, warm_sh.stats)
+        records.append({
+            "query": name,
+            "rows": len(rows_sh),
+            "single_ms": _best_of(pq_si.run, repeats) * 1e3,
+            "sharded_ms": _best_of(pq_sh.run, repeats) * 1e3,
+            "single_max_bucket": warm_si.stats.peak_join_bucket,
+            "per_shard_max_bucket": warm_sh.stats.peak_join_bucket,
+            "shuffles_emitted": warm_sh.stats.n_shuffles_emitted,
+            "shuffles_elided": warm_sh.stats.n_shuffles_elided,
+            "broadcast_joins": warm_sh.stats.n_broadcast_joins,
+        })
+    return records
+
+
 def bench_sharded(scale: int, repeats: int) -> list[dict]:
     """D1 + D2: the sharded engine vs the single-device engine on the
-    LUBM join-heavy (D1) and subject-star (D2) queries, at forced host
-    device counts 1 and 4.
+    LUBM join-heavy (D1) and subject-star (D2) queries, on 1 and 4
+    devices.
 
-    Each device count runs in a SUBPROCESS (bench_sharded_prog.py) so XLA
-    can be told the device count before jax initialises. Asserts the
-    structural wins at 4 devices so a sharding regression fails the bench
-    (and the distributed-smoke CI job running it):
+    Asserts the structural wins at 4 devices so a sharding regression
+    fails the bench (and the distributed-smoke CI job running it):
 
       * D1 — per-shard max join bucket strictly below the single-device
         bucket on the join-heavy queries;
@@ -160,26 +216,11 @@ def bench_sharded(scale: int, repeats: int) -> list[dict]:
         than on the 1-device mesh (map-side joins + collective/compute
         overlap turn the shard count into wall-clock, not just memory).
     """
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src")
-    by_dev: dict[int, list[dict]] = {}
-    for n_dev in D1_DEVICE_COUNTS:
-        proc = subprocess.run(
-            [sys.executable,
-             os.path.join(root, "benchmarks", "bench_sharded_prog.py"),
-             str(n_dev), str(scale), str(repeats)],
-            capture_output=True, text=True, timeout=1200, env=env,
-        )
-        assert proc.returncode == 0, (
-            f"D1 prog failed at n_dev={n_dev}:\n{proc.stdout}\n"
-            f"{proc.stderr}"
-        )
-        payload = next(
-            line for line in proc.stdout.splitlines()
-            if line.startswith("BENCH_JSON: ")
-        )
-        by_dev[n_dev] = json.loads(payload[len("BENCH_JSON: "):])["records"]
+    store = lubm.generate(scale=scale, seed=0, join_shapes=True)
+    by_dev = {
+        n_dev: _sharded_records(store, n_dev, repeats)
+        for n_dev in D1_DEVICE_COUNTS
+    }
     d1_set = set(D1_QUERIES)
     out = []
     wall_wins = []
@@ -449,7 +490,20 @@ def bench(scale: int = 2, repeats: int = 20, seed: int = 0) -> list[dict]:
     return out
 
 
+def force_host_devices(n: int) -> None:
+    """Give the CPU backend `n` devices for the D-series meshes. Must run
+    before jax initialises a backend; the flag changes nothing for an
+    accelerator's devices."""
+    flag = f"--xla_force_host_platform_device_count={n}"
+    if flag not in os.environ.get("XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (
+            flag + " " + os.environ.get("XLA_FLAGS", "")
+        ).strip()
+
+
 def main() -> None:
+    force_host_devices(max(D1_DEVICE_COUNTS))
+    enable_compile_cache()
     args = [a for a in sys.argv[1:]]
     quick = "--quick" in args
     sharded_only = "--sharded-only" in args
@@ -521,9 +575,8 @@ def main() -> None:
             json.dump({"scale": scale, "repeats": repeats,
                        "updates": w1}, f, indent=2)
         print("# wrote BENCH_7.json")
-    # D1 + D2: sharded vs single-device execution, 1 vs 4 forced host
-    # devices. Runs on CPU too (subprocesses force the device count);
-    # prints the shard-count scaling and asserts the per-shard bucket win
+    # D1 + D2: sharded vs single-device execution on 1 vs 4 devices
+    # (forced host devices on the CPU); prints the shard-count scaling and asserts the per-shard bucket win
     # (D1) and the zero-shuffle subject-star + 4-device wall-time win (D2).
     sharded_records = bench_sharded(scale, repeats)
     for r in sharded_records:

@@ -51,6 +51,7 @@ from repro.obs import (
     quantile_from_samples,
     validate_chrome_events,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sparql import lubm
 from repro.sparql.engine import QueryEngine
 from repro.serve.sparql_server import SPARQLServer
@@ -417,6 +418,7 @@ def bench_obs(store, quick: bool) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     args = sys.argv[1:]
     quick = "--quick" in args
     obs_only = "--obs-only" in args

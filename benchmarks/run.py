@@ -86,6 +86,11 @@ def bench_kernels() -> None:
 
 def main() -> None:
     from benchmarks import bench_join, bench_query
+    from repro.launch.compile_cache import enable_compile_cache
+
+    # before anything initialises jax: the D-series meshes need 4 devices
+    bench_query.force_host_devices(max(bench_query.D1_DEVICE_COUNTS))
+    enable_compile_cache()
 
     bench_join.main()
     bench_query.main()

@@ -18,7 +18,7 @@ from repro.core import compat  # noqa: E402
 from repro.core.distributed import make_distributed_join  # noqa: E402
 from repro.core.relation import Relation  # noqa: E402
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = compat.make_mesh((2, 4), ("data", "model"))
 n = 1 << 12
 rng = np.random.default_rng(0)
 left = Relation.from_numpy(("?x", "?y"), np.stack(

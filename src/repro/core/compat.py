@@ -1,81 +1,51 @@
-"""Version-portable mesh / shard_map API.
+"""Mesh / shard_map helpers, one spelling each for the installed jax.
 
-The repo targets the modern jax surface (`jax.shard_map`, `jax.set_mesh`,
-`check_vma`), but CI and local images may carry older releases where the
-same machinery lives under `jax.experimental.shard_map` (with the
-`check_rep` spelling) and the mesh context is entered by using the Mesh
-object itself as a context manager. Everything that touches a mesh goes
-through these two helpers so a jax upgrade/downgrade is a no-op for the
-rest of the codebase.
+Everything that builds or enters a mesh goes through here, so the mesh
+axis types and the shard_map flags are decided in one place.
 """
 from __future__ import annotations
 
+from collections.abc import Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices=None) -> jax.sharding.Mesh:
+    """`jax.make_mesh` with `Auto` axes.
+
+    jax's default is `Explicit` axes, under which an eager op on a
+    row-sharded array (a gather, a sort) must name its output sharding;
+    the engine's host-driven calibration runs such ops eagerly and lets
+    XLA choose, which `Auto` axes allow."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(AxisType.Auto,) * len(axes), devices=devices,
+    )
 
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None,
               check_vma: bool = False):
-    """`jax.shard_map` where available, else the experimental spelling
-    (whose `check_rep` flag is the old name for `check_vma`).
-
-    `mesh=None` means "the ambient mesh" — supported natively by modern
-    jax; on older releases it is resolved eagerly from the mesh context
-    entered via `set_mesh` (so the context must be active when the mapped
-    function is built, which every caller here satisfies)."""
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if mesh is None else {"mesh": mesh}
-        return jax.shard_map(
-            f,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-            **kwargs,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if mesh is None:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        if mesh.empty:
-            raise ValueError(
-                "shard_map without an explicit mesh needs an active mesh "
-                "context (use repro.core.compat.set_mesh)"
-            )
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=check_vma,
+    """`jax.shard_map`; `mesh=None` means the ambient mesh (`set_mesh`)."""
+    kwargs = {} if mesh is None else {"mesh": mesh}
+    return jax.shard_map(
+        f, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma,
+        **kwargs,
     )
 
 
 def axis_size(axis_name) -> int:
-    """Static size of a mapped mesh axis, from inside shard_map.
-
-    `jax.lax.axis_size` where available; older jax gets the same constant
-    from `psum(1, axis)` (a sum of the unmapped literal 1 folds to the
-    axis size at trace time)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Static size of a mapped mesh axis, from inside shard_map."""
+    return jax.lax.axis_size(axis_name)
 
 
 def ambient_mesh():
-    """The mesh made ambient by `set_mesh` (abstract on modern jax, the
-    physical mesh entered as a context on older releases)."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src.mesh import thread_resources
-
-    return thread_resources.env.physical_mesh
+    """The (abstract) mesh made ambient by `set_mesh`."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def set_mesh(mesh):
     """Context manager making `mesh` the ambient mesh (so bare
-    PartitionSpecs in `with_sharding_constraint` resolve against it).
-    Older jax enters the context via the Mesh object itself."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    PartitionSpecs in `with_sharding_constraint` resolve against it)."""
+    return jax.set_mesh(mesh)

@@ -19,14 +19,8 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def sort_pairs(
-    keys: jax.Array,
-    vals: jax.Array,
-    *,
-    use_kernel: bool = True,
-    interpret: bool | None = None,
-):
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
+def sort_pairs(keys: jax.Array, vals: jax.Array, *, use_kernel: bool = True):
     """Sort (keys, vals) by key ascending; any length, int32.
 
     Padding keys (INT32_MAX) sort to the end and are sliced off. NOTE: the
@@ -36,11 +30,10 @@ def sort_pairs(
     n = keys.shape[0]
     if not use_kernel or n > _MAX_KERNEL_N or n < 2:
         return _ref.sort_pairs(keys, vals)
-    interpret = default_interpret() if interpret is None else interpret
     m = _next_pow2(n)
     pk = jnp.full((m,), _PAD_KEY, jnp.int32).at[:n].set(keys.astype(jnp.int32))
     pv = jnp.zeros((m,), jnp.int32).at[:n].set(vals.astype(jnp.int32))
-    sk, sv = _k.bitonic_sort_pairs(pk, pv, interpret=interpret)
+    sk, sv = _k.bitonic_sort_pairs(pk, pv, interpret=default_interpret())
     return sk[:n], sv[:n]
 
 

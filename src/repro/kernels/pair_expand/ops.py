@@ -11,15 +11,15 @@ from repro.kernels.pair_expand import kernel as _k
 from repro.kernels.pair_expand import ref as _ref
 
 
-@functools.partial(jax.jit, static_argnames=("capacity", "use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("capacity", "use_kernel"))
 def pair_expand(prefix: jax.Array, counts: jax.Array, capacity: int, *,
-                use_kernel: bool = True, interpret: bool | None = None):
-    """For each output slot: (sorted-left row, offset within group, valid)."""
+                use_kernel: bool = True):
+    """For each output slot: (sorted-left row, offset within group, valid).
+    `prefix` is the inclusive prefix sum of `counts`; the kernel needs
+    only the prefix."""
     if not use_kernel or prefix.shape[0] < 2:
         return _ref.pair_expand(prefix, counts, capacity)
-    interpret = default_interpret() if interpret is None else interpret
     cap = ((capacity + _k.BLOCK - 1) // _k.BLOCK) * _k.BLOCK
     i, off, valid = _k.pair_expand_pallas(
-        prefix.astype(jnp.int32), counts.astype(jnp.int32), cap,
-        interpret=interpret)
+        prefix.astype(jnp.int32), cap, interpret=default_interpret())
     return i[:capacity], off[:capacity], valid[:capacity].astype(bool)

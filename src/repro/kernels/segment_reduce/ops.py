@@ -14,19 +14,17 @@ from repro.kernels.segment_reduce import ref as _ref
 _MAX_SEGMENTS = 4096
 
 
-@functools.partial(jax.jit, static_argnames=("num_segments", "use_kernel",
-                                              "interpret"))
+@functools.partial(jax.jit, static_argnames=("num_segments", "use_kernel"))
 def sorted_segment_sum(data: jax.Array, ids: jax.Array, num_segments: int, *,
-                       use_kernel: bool = True, interpret: bool | None = None):
+                       use_kernel: bool = True):
     """Sum rows of `data` by sorted segment id. ids >= num_segments drop."""
     n, d = data.shape
     if not use_kernel or num_segments > _MAX_SEGMENTS:
         return _ref.sorted_segment_sum(data, ids, num_segments)
-    interpret = default_interpret() if interpret is None else interpret
     m = ((n + _k.BLOCK_N - 1) // _k.BLOCK_N) * _k.BLOCK_N
     pdata = jnp.zeros((m, d), data.dtype).at[:n].set(data)
     # out-of-range id => all-zero one-hot row => dropped (matches ref's drop)
     pids = jnp.full((m,), num_segments, jnp.int32).at[:n].set(ids.astype(jnp.int32))
     out = _k.sorted_segment_sum_pallas(pdata, pids, num_segments,
-                                       interpret=interpret)
+                                       interpret=default_interpret())
     return out.astype(data.dtype)

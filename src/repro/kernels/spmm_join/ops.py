@@ -27,10 +27,9 @@ def _pad_to(x: jax.Array, multiple: int, value: int) -> jax.Array:
     return jnp.pad(x, (0, n_pad - n), constant_values=jnp.int32(value))
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
 def match_layout(left_keys: jax.Array, right_keys: jax.Array, *,
-                 use_kernel: bool = True,
-                 interpret: bool | None = None):
+                 use_kernel: bool = True):
     """(counts[i], first[i], b[i], cl[j]): the full output layout of the
     join, from one dense eq/lt pass (see ref.match_layout).
 
@@ -41,17 +40,16 @@ def match_layout(left_keys: jax.Array, right_keys: jax.Array, *,
     after every real row (so no real row's b sees it)."""
     if not use_kernel or left_keys.shape[0] < 2 or right_keys.shape[0] < 2:
         return _ref.match_layout(left_keys, right_keys)
-    interpret = default_interpret() if interpret is None else interpret
     lp = _pad_to(left_keys.astype(jnp.int32), _k.BLOCK, _PAD_LEFT)
-    rp = _pad_to(right_keys.astype(jnp.int32), _k.CHUNK, _PAD_RIGHT)
-    counts, first, b, cl = _k.match_layout_pallas(lp, rp, interpret=interpret)
+    rp = _pad_to(right_keys.astype(jnp.int32), _k.LANES, _PAD_RIGHT)
+    counts, first, b, cl = _k.match_layout_pallas(
+        lp, rp, interpret=default_interpret())
     n_l, n_r = left_keys.shape[0], right_keys.shape[0]
     return counts[:n_l], first[:n_l], b[:n_l], cl[:n_r]
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def sort_ranks(keys: jax.Array, *, use_kernel: bool = True,
-               interpret: bool | None = None) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
+def sort_ranks(keys: jax.Array, *, use_kernel: bool = True) -> jax.Array:
     """rank[j] = the row's stable sorted position (a permutation of 0..n-1).
 
     Padding with INVALID_LEFT (int32 max) is sound for either side's keys:
@@ -60,7 +58,6 @@ def sort_ranks(keys: jax.Array, *, use_kernel: bool = True,
     rank inside 0..n-1 — padded rows rank strictly at the tail."""
     if not use_kernel or keys.shape[0] < 2:
         return _ref.sort_ranks(keys)
-    interpret = default_interpret() if interpret is None else interpret
     kp = _pad_to(keys.astype(jnp.int32), _k.BLOCK, _PAD_LEFT)
-    out = _k.sort_ranks_pallas(kp, interpret=interpret)
+    out = _k.sort_ranks_pallas(kp, interpret=default_interpret())
     return out[: keys.shape[0]]

@@ -5,6 +5,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.core.compat import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod.
@@ -15,7 +17,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1,
@@ -23,5 +25,5 @@ def make_local_mesh(data: int = 1, model: int = 1,
     """Small mesh over however many devices this host actually has
     (smoke tests, examples, CI)."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -16,8 +16,8 @@ from repro.core import compat
 def serve_sparql(scale: int, n_queries: int, shards: int = 0) -> None:
     """`shards > 0` opens the store SHARDED: subject-hash partitioned over
     a `shards`-device mesh, queries served by the distributed executor
-    (one shard_map dispatch per warm query). Force host devices first,
-    e.g. XLA_FLAGS=--xla_force_host_platform_device_count=4 for CPU."""
+    (one shard_map dispatch per warm query). On the CPU, force host
+    devices first: XLA_FLAGS=--xla_force_host_platform_device_count=4."""
     from repro.serve.sparql_server import SPARQLServer
     from repro.sparql.engine import QueryEngine, ShardedQueryEngine
     from repro.sparql.lubm import QUERIES, generate
@@ -87,6 +87,9 @@ def main() -> None:
                     help="open the store sharded over this many devices "
                          "(0 = single-device store)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.mode == "sparql":
         serve_sparql(args.scale, args.n_queries, args.shards)
     else:

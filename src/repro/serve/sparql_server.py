@@ -459,6 +459,7 @@ class SPARQLServer:
                 "stacked_dispatches": sd,
                 "stacked_queries": sq,
                 "queries_per_dispatch": sq / sd if sd else 0.0,
+                "fallbacks": eng.stacked_fallbacks,
                 "batch_width_hist": dict(sorted(width_hist.items())),
                 "arrival_batch_hist": dict(sorted(arrival_hist.items())),
                 "padding": {

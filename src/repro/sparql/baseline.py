@@ -17,7 +17,9 @@ same partial matches in, same result set out, join time compared.
 
 `reference_rows` additionally evaluates a full parsed Query — BGP, UNION,
 OPTIONAL, FILTER (boolean combinations), projection, DISTINCT — by
-backtracking over decoded triples. It is the differential oracle the prepared-query tests compare
+extending bindings one triple pattern at a time over decoded triples,
+through a hash lookup per binding. It is the differential oracle the
+prepared-query tests compare
 the device algebra against (LIMIT/OFFSET are left to the caller, since
 any row subset of the right size is a correct slice).
 """
@@ -73,25 +75,46 @@ def _term_numeric(term: str):
     return np.float32(term) if _NUMERIC.fullmatch(term) else None
 
 
-def _extend(bindings: list[dict], triples, tp) -> list[dict]:
-    """All extensions of each binding by one triple pattern (backtracking)."""
-    out = []
-    for b in bindings:
-        for s, p, o in triples:
-            nb = dict(b)
-            ok = True
-            for term, val in ((tp.s, s), (tp.p, p), (tp.o, o)):
-                if term.startswith("?"):
-                    if nb.get(term, val) != val:
-                        ok = False
-                        break
-                    nb[term] = val
-                elif term != val:
-                    ok = False
-                    break
-            if ok:
+class _PatternMatcher:
+    """One triple pattern's matches: the triples fitting its constants
+    (and repeated variables), hashed on whichever of its variables a
+    binding already fixes, so extending a binding is one lookup."""
+
+    def __init__(self, triples, tp):
+        terms = (tp.s, tp.p, tp.o)
+        consts = [(i, t) for i, t in enumerate(terms) if not t.startswith("?")]
+        self.var_names = list(
+            dict.fromkeys(t for t in terms if t.startswith("?"))
+        )
+        self.matches: list[dict] = []  # per matching triple: {var: term}
+        for triple in triples:
+            if any(triple[i] != t for i, t in consts):
+                continue
+            vals: dict = {}
+            if all(
+                vals.setdefault(t, v) == v
+                for t, v in zip(terms, triple) if t.startswith("?")
+            ):
+                self.matches.append(vals)
+        self._indexes: dict[tuple, dict] = {}  # bound vars -> index
+
+    def extend(self, bindings: list[dict]) -> list[dict]:
+        """All extensions of each binding, in binding then triple order."""
+        out = []
+        for b in bindings:
+            bound = tuple(v for v in self.var_names if v in b)
+            index = self._indexes.get(bound)
+            if index is None:
+                index = self._indexes[bound] = {}
+                for vals in self.matches:
+                    index.setdefault(
+                        tuple(vals[v] for v in bound), []
+                    ).append(vals)
+            for vals in index.get(tuple(b[v] for v in bound), ()):
+                nb = dict(b)
+                nb.update(vals)
                 out.append(nb)
-    return out
+        return out
 
 
 def _filter_true(cond, b: dict) -> bool:
@@ -137,9 +160,17 @@ def reference_rows(store, q) -> list[dict[str, str]]:
     slice): projected rows as {var: term} dicts, unbound vars omitted."""
     d = store.dictionary
     triples = [tuple(d.decode(int(t)) for t in row) for row in store.triples]
+    matchers: dict[tuple, _PatternMatcher] = {}
+
+    def _extend(bindings: list[dict], tp) -> list[dict]:
+        key = (tp.s, tp.p, tp.o)
+        if key not in matchers:
+            matchers[key] = _PatternMatcher(triples, tp)
+        return matchers[key].extend(bindings)
+
     bindings = [dict()]
     for tp in q.patterns:
-        bindings = _extend(bindings, triples, tp)
+        bindings = _extend(bindings, tp)
     if getattr(q, "unions", ()):
         # multiset union: each branch extends the required bindings
         # independently; rows keep other branches' variables unbound
@@ -147,7 +178,7 @@ def reference_rows(store, q) -> list[dict[str, str]]:
         for branch in q.unions:
             ext = list(bindings)
             for tp in branch:
-                ext = _extend(ext, triples, tp)
+                ext = _extend(ext, tp)
             unioned.extend(ext)
         bindings = unioned
     for group in q.optionals:
@@ -155,7 +186,7 @@ def reference_rows(store, q) -> list[dict[str, str]]:
         for b in bindings:
             ext = [b]
             for tp in group:
-                ext = _extend(ext, triples, tp)
+                ext = _extend(ext, tp)
             joined.extend(ext if ext else [b])  # no match: keep b unextended
         bindings = joined
     for cond in q.filters:

@@ -62,6 +62,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.core import compat
 from repro.core import executor as ex
 from repro.core import mr_join as mj
 from repro.core import plan_ir
@@ -585,6 +586,9 @@ class QueryEngine:
         self.batch_width_hist: dict[int, int] = {}
         self.stacked_dispatches = 0
         self.stacked_queries = 0
+        # stacked chunks re-run one query at a time after a lane's regrow
+        # passed max_capacity
+        self.stacked_fallbacks = 0
         self.last_batch: list[BatchGroupStats] = []
         # cross-shape padding counters: merges taken / rejected by the
         # cost guard, and the cell ledger behind the waste ratio
@@ -632,6 +636,10 @@ class QueryEngine:
             "stacked_queries": m.counter(
                 "mapsq_stacked_queries_total",
                 "queries served by stacked launches"),
+            "stacked_fallbacks": m.counter(
+                "mapsq_stacked_fallbacks_total",
+                "stacked chunks re-run sequentially after a capacity "
+                "overflow"),
             "padded_groups": m.counter(
                 "mapsq_padding_groups_total",
                 "cross-shape padded merges taken"),
@@ -672,6 +680,7 @@ class QueryEngine:
             g["scan_evictions"].set_total(sc.get("evictions", 0))
             g["stacked_dispatches"].set_total(self.stacked_dispatches)
             g["stacked_queries"].set_total(self.stacked_queries)
+            g["stacked_fallbacks"].set_total(self.stacked_fallbacks)
             g["padded_groups"].set_total(self.padded_groups)
             g["pad_rejects"].set_total(self.pad_rejects)
             g["padded_cells"].set_total(self.padded_cells)
@@ -965,14 +974,14 @@ class QueryEngine:
                         max(e.join_caps[j] for e in entries)
                         for j in range(len(entries[0].join_caps))
                     )
+                    # join caps are the elementwise max of caps that
+                    # already compiled, so no capacity error is expected:
+                    # a compile or device error propagates
                     sink = ExecStats()
-                    try:
-                        self._compile_entry(
-                            padded_shape, join_caps,
-                            self._template_scans(padded_shape), None, sink,
-                        )
-                    except Exception:
-                        ok = False
+                    self._compile_entry(
+                        padded_shape, join_caps,
+                        self._template_scans(padded_shape), None, sink,
+                    )
                     n_compiles = sink.n_compiles
             if not ok:
                 for s in members:
@@ -1055,11 +1064,12 @@ class QueryEngine:
                 self._run_chunk_stacked(
                     shape, chunk, ctxs, prepared, out, group, defer, traces
                 )
-            except Exception:
-                # stacked dispatch failed (e.g. bucket growth past
-                # max_capacity): isolate errors by re-running the chunk's
-                # queries sequentially so only the culprit raises
+            except MemoryError:
+                # a lane's bucket regrow passed max_capacity: isolate it by
+                # re-running the chunk's queries sequentially so only the
+                # culprit raises (compile and device errors propagate)
                 group.fallback = True
+                self.stacked_fallbacks += 1
                 for i in chunk:
                     out[i] = self._run_single(
                         prepared[i], group, defer, traces[i]
@@ -1909,19 +1919,16 @@ class QueryEngine:
                 2 if entry.shape.has_slice else 0
             )
             n_f = entry.shape.n_consts[1]
-            try:
-                entry.batched[key] = ex.compile_plan_batched(
-                    entry.compiled.plan,
-                    scans_b,
-                    sds((w, n_i), jnp.int32),
-                    sds((w, n_f), jnp.float32),
-                    self.store.numeric_values_device(),
-                    sds((w,), jnp.bool_),
-                    use_kernel=self.use_kernel,
-                    scan_axes=axes,
-                )
-            except Exception:
-                continue  # a stale width must never fail a live query
+            entry.batched[key] = ex.compile_plan_batched(
+                entry.compiled.plan,
+                scans_b,
+                sds((w, n_i), jnp.int32),
+                sds((w, n_f), jnp.float32),
+                self.store.numeric_values_device(),
+                sds((w,), jnp.bool_),
+                use_kernel=self.use_kernel,
+                scan_axes=axes,
+            )
             stats.n_compiles += 1
             self.plan_cache.compiles += 1
 
@@ -2192,7 +2199,7 @@ class ShardedQueryEngine(QueryEngine):
         # and an engine-level override — pass straight through: shard-local
         # joins after a shuffle/elision are ordinary joins
         if self.mesh is None:
-            self.mesh = jax.make_mesh(
+            self.mesh = compat.make_mesh(
                 (jax.device_count(),), (self.axis_name,)
             )
         self.axis_names = tuple(self.mesh.axis_names)

@@ -243,7 +243,10 @@ _BROADCAST_ROWS = 2048
 # walks: there the MR backend's two argsorts are pure overhead, while a hot
 # (skewed) key makes its expansion scale with the dense product anyway
 MATRIX_THRESHOLD = 0.5
-# never go dense past this |L| x |R| work bound, whatever the skew
+# never go dense past this many key compares, whatever the skew: the
+# |L| x |R| layout grid plus the right side's |R| x |R| rank pass. The
+# rank bound also keeps the right side (resident in the kernels' VMEM)
+# under 2048 rows
 MATRIX_DENSE_CAP = 1 << 22
 
 
@@ -252,7 +255,7 @@ def _choose_backend(a: _State, b: _State, est: float) -> str:
     if not shared:
         return "mr"  # cross join: one algebra, slot value is padding
     work = a.card * b.card
-    if work <= 0 or work > MATRIX_DENSE_CAP:
+    if work <= 0 or work + b.card * b.card > MATRIX_DENSE_CAP:
         return "mr"
     sigma = est / work
     skew = max(max(a.skew.get(v, 1.0), b.skew.get(v, 1.0)) for v in shared)

@@ -13,6 +13,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.core import compat  # noqa: E402
 from repro.core import distributed as dj  # noqa: E402
 from repro.core.relation import Relation  # noqa: E402
 
@@ -56,9 +57,9 @@ def main():
     assert jax.device_count() == 8, jax.device_count()
     rng = np.random.RandomState(0)
     # flat shuffle on one axis
-    mesh1 = jax.make_mesh((8,), ("data",))
+    mesh1 = compat.make_mesh((8,), ("data",))
     # hierarchical: pod x data
-    mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh2 = compat.make_mesh((2, 4), ("pod", "data"))
     for seed in range(3):
         rng = np.random.RandomState(seed)
         l_rows = rng.randint(0, 12, size=(rng.randint(8, 60), 2)).astype(np.int32)

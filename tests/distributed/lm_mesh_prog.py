@@ -30,7 +30,7 @@ def main():
         n_experts=6, top_k=2, d_expert_ff=32, capacity_factor=8.0,
         kv_chunk=8, remat=True,
     )
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
     params = T.init_params(jax.random.PRNGKey(0), cfg, ep=2)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, cfg.vocab)
     labels = jax.random.randint(jax.random.PRNGKey(2), (8, 32), 0, cfg.vocab)
@@ -47,7 +47,7 @@ def main():
     print(f"train_step on 2x2x2 mesh: loss={loss_mesh:.4f}")
 
     # sharding invariance: same loss on a single-device mesh
-    mesh1 = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh1 = compat.make_mesh((1, 1, 1), ("pod", "data", "model"))
     params1 = T.init_params(jax.random.PRNGKey(0), cfg, ep=2)
     step1 = jax.jit(T.make_loss_fn(cfg, mesh1, True))
     with compat.set_mesh(mesh1):

@@ -47,7 +47,7 @@ def dense_moe_reference(p: M.MoEParams, x, st: M.MoESettings, e_pad: int):
 
 def main():
     assert jax.device_count() == 8, jax.devices()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = compat.make_mesh((2, 4), ("data", "model"))
     st = M.MoESettings(n_experts=6, top_k=2, d_expert_ff=32,
                        capacity_factor=8.0)  # high cf => no drops
     ep = 4

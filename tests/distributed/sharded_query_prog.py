@@ -27,6 +27,7 @@ os.environ["XLA_FLAGS"] = (
 
 import jax  # noqa: E402
 
+from repro.core import compat  # noqa: E402
 from repro.sparql import lubm  # noqa: E402
 from repro.sparql.baseline import reference_rows  # noqa: E402
 from repro.sparql.engine import QueryEngine, ShardedQueryEngine  # noqa: E402
@@ -124,7 +125,7 @@ def main():
     if N_DEV == 8:
         # hierarchical 2x4 (pod x data) mesh: the two-stage shuffle routes
         # inter-pod first, then intra-pod — results must stay identical
-        mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh2 = compat.make_mesh((2, 4), ("pod", "data"))
         hier = ShardedQueryEngine(shard_store(store, 8), mesh=mesh2)
         for name in ("Q2", "Q9", "U1"):
             text = queries[name]

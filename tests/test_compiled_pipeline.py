@@ -236,3 +236,28 @@ def test_parser_semicolon_trailing_and_errors():
     assert len(q.patterns) == 1
     with pytest.raises(ParseError):
         parse('SELECT ?x WHERE { ?x <p> ; <o> . }')  # ; needs a full p-o pair
+
+
+# ------------------------------------------------- persistent compile cache
+def test_compile_cache_env_dir_wins_else_fixed_checkout_path(monkeypatch):
+    """Entry points keep jax's persistent cache where
+    JAX_COMPILATION_CACHE_DIR says (setting nothing in code), else at
+    <checkout>/.jax_cache — a fixed path, since it is part of the key."""
+    import pathlib
+
+    import jax
+
+    from repro.launch import compile_cache as cc
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cc.ENV_VAR, "elsewhere")
+        assert cc.enable_compile_cache() == "elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(cc.ENV_VAR)
+        assert cc.enable_compile_cache() == str(cc.DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(cc.DEFAULT_DIR)
+        checkout = pathlib.Path(__file__).resolve().parents[1]
+        assert cc.DEFAULT_DIR == checkout / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -16,7 +16,7 @@ from repro.models.gnn import meshgraphnet as mgn
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return compat.make_mesh((1, 1), ("data", "model"))
 
 
 def _graph(arch, d_feat, seed=3):

@@ -150,7 +150,7 @@ def test_match_layout_shapes(n_l, n_r):
     want = _layout_oracle(lk, rk)
     for use_kernel in (False, True):
         got = spmm_ops.match_layout(jnp.asarray(lk), jnp.asarray(rk),
-                                    use_kernel=use_kernel, interpret=True)
+                                    use_kernel=use_kernel)
         for g, w in zip(got, want):
             np.testing.assert_array_equal(np.asarray(g), w)
 
@@ -175,8 +175,7 @@ def test_sort_ranks_is_stable_sorted_position(n):
     want = np.empty(n, np.int64)
     want[order] = np.arange(n)
     for use_kernel in (False, True):
-        pos = spmm_ops.sort_ranks(jnp.asarray(keys), use_kernel=use_kernel,
-                                  interpret=True)
+        pos = spmm_ops.sort_ranks(jnp.asarray(keys), use_kernel=use_kernel)
         np.testing.assert_array_equal(np.asarray(pos), want)
 
 
@@ -187,7 +186,7 @@ def test_match_layout_hypothesis(ls, rs):
     lk = np.array(ls, np.int32)
     rk = np.array(rs, np.int32)
     got = spmm_ops.match_layout(jnp.asarray(lk), jnp.asarray(rk),
-                                use_kernel=True, interpret=True)
+                                use_kernel=True)
     for g, w in zip(got, _layout_oracle(lk, rk)):
         np.testing.assert_array_equal(np.asarray(g), w)
 

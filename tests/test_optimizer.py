@@ -574,6 +574,22 @@ def test_uniform_joins_stay_on_mr_backend():
         assert "matrix_join" not in eng.explain(lubm.QUERIES[name])
 
 
+@pytest.mark.parametrize("n_left,n_right,want", [
+    (64.0, 1024.0, "matrix"),  # 64*1024 + 1024^2 compares: under the cap
+    (1.0, 4096.0, "mr"),  # the right side's 4096^2 rank pass is not
+])
+def test_matrix_rule_counts_the_right_rank_pass(n_left, n_right, want):
+    """A hot-key join goes dense only while the layout grid PLUS the
+    right side's |R|^2 rank pass fit MATRIX_DENSE_CAP, which also keeps
+    the kernels' VMEM-resident right side small."""
+    def state(card):
+        return optimizer._State(card, {"?k": 1.0}, {"?k": 100.0}, ("?k",))
+
+    got = optimizer._choose_backend(
+        state(n_left), state(n_right), n_left * n_right)
+    assert got == want
+
+
 def test_join_backend_override_validation():
     store = student_store()
     with pytest.raises(ValueError, match="join_backend"):
