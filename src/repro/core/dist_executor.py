@@ -68,6 +68,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import compat
 from repro.core import distributed as dj
+from repro.core import executor as ex
 from repro.core import matrix_join as mxj
 from repro.core import mr_join as mj
 from repro.core.plan_ir import (
@@ -413,6 +414,7 @@ def _local_program(
     n_stages = len(axis_names)
     site_nodes = shuffle_site_nodes(plan)
     site_of = {id(n): i for i, n in enumerate(site_nodes)}
+    slot_of = {id(n): k for k, n in enumerate(ex.join_slot_nodes(plan))}
     assert len(shuffle_caps) == len(site_nodes) * n_stages, (
         shuffle_caps, len(site_nodes), n_stages,
     )
@@ -474,7 +476,11 @@ def _local_program(
             hit = memo.get(id(node))
             if hit is not None:
                 return hit
-            rel = _eval(node)
+            # children first, so each node's scope holds its own ops only
+            for kid in child_nodes(node):
+                eval_node(kid)
+            with jax.named_scope(ex.op_scope(node, slot_of)):
+                rel = _eval(node)
             memo[id(node)] = rel
             return rel
 
@@ -487,14 +493,15 @@ def _local_program(
                 left = eval_node(node.left)
                 right = eval_node(node.right)
                 need, ov_sh = zero_acct()
-                if st.left == "shuffle":
-                    left, ov, nd = shuffled(node, "left", left)
-                    need, ov_sh = jnp.maximum(need, nd), ov_sh | ov
-                if st.right == "shuffle":
-                    right, ov, nd = shuffled(node, "right", right)
-                    need, ov_sh = jnp.maximum(need, nd), ov_sh | ov
-                elif st.right == "broadcast":
-                    right = replicate(right)
+                with jax.named_scope("shuffle"):
+                    if st.left == "shuffle":
+                        left, ov, nd = shuffled(node, "left", left)
+                        need, ov_sh = jnp.maximum(need, nd), ov_sh | ov
+                    if st.right == "shuffle":
+                        right, ov, nd = shuffled(node, "right", right)
+                        need, ov_sh = jnp.maximum(need, nd), ov_sh | ov
+                    elif st.right == "broadcast":
+                        right = replicate(right)
                 if isinstance(node, LeftJoin):
                     ljoin = (
                         mxj.matrix_left_join if node.backend == "matrix"
@@ -521,7 +528,8 @@ def _local_program(
                 si = site_of[id(node)]
                 left = eval_node(node.left)
                 right = eval_node(node.right)
-                r_all = replicate(right)
+                with jax.named_scope("shuffle"):
+                    r_all = replicate(right)
                 # every (local-left, global-right) position is enumerated:
                 # exact, like the single-device cross join
                 out, total, ovf = mj.cross_join(
@@ -551,10 +559,11 @@ def _local_program(
                     # bucket; elided when the child is already hash-
                     # partitioned on a subset of its columns
                     idx = list(range(child.n_cols))
-                    cols, valid, ov, need = dj.shuffle_by_key(
-                        child.cols, child.valid, idx, axis_names,
-                        site_caps(si),
-                    )
+                    with jax.named_scope("shuffle"):
+                        cols, valid, ov, need = dj.shuffle_by_key(
+                            child.cols, child.valid, idx, axis_names,
+                            site_caps(si),
+                        )
                     child = Relation(child.schema, cols, valid)
                     sh_needs[si], sh_flags[si] = need, ov
                 else:
@@ -599,10 +608,12 @@ def _local_program(
                 ):
                     rel = eval_node(child)
                     idx = [rel.schema.index(v) for v in node.key_vars]
-                    slots.issue(
-                        (id(node), side), rel.cols, rel.valid, idx,
-                        axis_names, site_caps(site_of[id(node)]),
-                    )
+                    with jax.named_scope(ex.op_scope(node, slot_of)), \
+                            jax.named_scope("shuffle"):
+                        slots.issue(
+                            (id(node), side), rel.cols, rel.valid, idx,
+                            axis_names, site_caps(site_of[id(node)]),
+                        )
 
         rel = eval_node(plan.root)
         n_joins = len(totals)
@@ -786,10 +797,11 @@ def lower_sharded_batched(
         num_vals: jax.Array,
         active: jax.Array,
     ) -> ShardedChainResult:
-        masked = tuple(
-            Relation(s.schema, s.cols, s.valid & active) for s in scans
-        )
-        res = local_run(masked, consts_i, consts_f, num_vals)
+        masked = []
+        for j, s in enumerate(scans):
+            with jax.named_scope(f"scan{j}"):
+                masked.append(Relation(s.schema, s.cols, s.valid & active))
+        res = local_run(tuple(masked), consts_i, consts_f, num_vals)
         return ShardedChainResult(
             res.relation,
             res.totals,

@@ -31,7 +31,9 @@ padded lanes contribute no rows and no overflow flags.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import re
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -52,6 +54,7 @@ from repro.core.plan_ir import (
     Scan,
     Slice,
     UnionAll,
+    child_nodes,
 )
 from repro.core.relation import Relation
 
@@ -74,6 +77,8 @@ def lower(
     by its left join — the order the engine calibrates join_caps in.
     """
 
+    slot_of = {id(n): k for k, n in enumerate(join_slot_nodes(plan))}
+
     def run(
         scans: tuple[Relation, ...],
         consts_i: jax.Array,
@@ -92,7 +97,11 @@ def lower(
             hit = memo.get(id(node))
             if hit is not None:
                 return hit
-            rel = _eval(node)
+            # children first, so each node's scope holds its own ops only
+            for kid in child_nodes(node):
+                eval_node(kid)
+            with jax.named_scope(op_scope(node, slot_of)):
+                rel = _eval(node)
             memo[id(node)] = rel
             return rel
 
@@ -192,6 +201,112 @@ def join_slot_nodes(plan: PhysicalPlan) -> list[PlanNode]:
     return slots
 
 
+# -- plan operators named on the device ---------------------------------------
+# `lower` opens a `jax.named_scope` per plan node (joins by slot, as
+# `join_slot_nodes` numbers them) and the join algorithms one per phase, so
+# every HLO instruction's metadata carries `op_name="jit(run)/join2/count/
+# ..."`. A profiler trace names device ops by HLO instruction only;
+# `hlo_op_scopes` recovers each instruction's scope from the executable.
+
+_NODE_SCOPES = {
+    Filter: "filter",
+    UnionAll: "union",
+    Project: "project",
+    Distinct: "distinct",
+    Slice: "slice",
+}
+_NODE_SCOPE = re.compile(
+    r"join\d+|scan\d+|filter|union|project|distinct|slice"
+)
+_JOIN_PHASE = re.compile(r"map|sort|count|expand|layout|shuffle")
+_WRAPPED = re.compile(r"[\w-]+\((.*)\)")
+_INSTR = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%([\w.\-]+)")
+
+
+def op_scope(node: PlanNode, slot_of: dict[int, int]) -> str:
+    """The device scope of one plan node: `join<slot>` for every join
+    (slot numbering of `join_slot_nodes`), `scan<j>` for scan input j."""
+    if id(node) in slot_of:
+        return f"join{slot_of[id(node)]}"
+    if isinstance(node, Scan):
+        return f"scan{node.index}"
+    return _NODE_SCOPES[type(node)]
+
+
+def scope_of(op_name: str) -> str:
+    """The plan-operator scope inside an HLO `op_name`:
+    'jit(run)/join2/count/jit(searchsorted)/while' -> 'join2/count';
+    transform wrappers ('vmap(join0)', 'vmap()', 'shard_map') are looked
+    through, and the last part, the primitive's own name, is never a
+    scope. '' when the op sits under no plan operator."""
+    parts: list[str] = []
+    for part in op_name.split("/")[1:-1]:
+        if part == "shard_map":
+            continue
+        m = _WRAPPED.fullmatch(part)
+        if m is not None:
+            part = m.group(1)
+            if not part:
+                continue
+        if not parts:
+            ok = _NODE_SCOPE.fullmatch(part)
+        else:
+            ok = len(parts) == 1 and parts[0].startswith("join") and (
+                _JOIN_PHASE.fullmatch(part))
+        if not ok:
+            break
+        parts.append(part)
+    return "/".join(parts)
+
+
+def hlo_op_scopes(hlo_text: str) -> dict[str, str]:
+    """HLO instruction name -> plan-operator scope, from a compiled
+    module's text (`Compiled.as_text()`). A fusion without metadata of its
+    own takes the most common scope of the computation it calls."""
+    own: dict[str, str] = {}
+    calls: dict[str, str] = {}
+    by_comp: dict[str, collections.Counter] = {}
+    comp = ""
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            comp = line.split()[0].lstrip("%")
+            if comp == "ENTRY":
+                comp = line.split()[1].lstrip("%")
+            continue
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        name = m.group(1)
+        on = _OP_NAME.search(line)
+        sc = scope_of(on.group(1)) if on else ""
+        own[name] = sc
+        if sc:
+            by_comp.setdefault(comp, collections.Counter())[sc] += 1
+        c = _CALLS.search(line)
+        if c is not None:
+            calls[name] = c.group(1)
+    for name, callee in calls.items():
+        if not own[name] and callee in by_comp:
+            own[name] = by_comp[callee].most_common(1)[0][0]
+    return own
+
+
+def module_key(compiled: Any) -> str:
+    """One AOT executable's (`jax.stages.Compiled`) name in `op_scopes()`
+    and on its `mapsq.launch` annotation: the HLO module's name and the
+    executable's fingerprint, `jit_run(<fingerprint>)`."""
+    rt = compiled.runtime_executable()
+    fp = rt.fingerprint
+    if isinstance(fp, bytes):
+        try:
+            fp = fp.decode()
+        except UnicodeDecodeError:
+            fp = fp.hex()
+    return f"{rt.hlo_modules()[0].name}({fp})"
+
+
 @dataclasses.dataclass
 class CompiledPlan:
     """An XLA executable specialised on one (shape, join-caps) point."""
@@ -271,9 +386,11 @@ def lower_batched(
         num_vals: jax.Array,
         active: jax.Array,
     ) -> ChainResult:
-        masked = tuple(
-            Relation(s.schema, s.cols, s.valid & active) for s in scans
-        )
+        masked = []
+        for j, s in enumerate(scans):
+            with jax.named_scope(f"scan{j}"):
+                masked.append(Relation(s.schema, s.cols, s.valid & active))
+        masked = tuple(masked)
         rel, totals, flags = base(masked, consts_i, consts_f, num_vals)
         return ChainResult(rel, totals, flags & active)
 

@@ -43,11 +43,13 @@ def _match_arrays(left: Relation, right: Relation, use_kernel: bool):
             f"cross join between {left.schema} and {right.schema}; "
             "use cross_join()"
         )
-    l_key, r_key = _map_phase(left, right, key_vars)
-    counts, first, b, cl = spmm_ops.match_layout(
-        l_key, r_key, use_kernel=use_kernel
-    )
-    pos_r = spmm_ops.sort_ranks(r_key, use_kernel=use_kernel)
+    with jax.named_scope("map"):
+        l_key, r_key = _map_phase(left, right, key_vars)
+    with jax.named_scope("layout"):
+        counts, first, b, cl = spmm_ops.match_layout(
+            l_key, r_key, use_kernel=use_kernel
+        )
+        pos_r = spmm_ops.sort_ranks(r_key, use_kernel=use_kernel)
     return counts, first, b, cl, pos_r
 
 
@@ -114,10 +116,13 @@ def matrix_join(
     mr_join: (result, exact_total, overflowed), schema = left vars then
     right vars not already bound, rows past capacity truncated exactly."""
     counts, first, b, cl, pos_r = _match_arrays(left, right, use_kernel)
-    li, rj, valid, total = _expand_gather(
-        counts, first, b, cl, pos_r, capacity
-    )
-    out_schema, _, cols = _joined_cols(left, right, li, rj, valid, capacity)
+    with jax.named_scope("expand"):
+        li, rj, valid, total = _expand_gather(
+            counts, first, b, cl, pos_r, capacity
+        )
+        out_schema, _, cols = _joined_cols(
+            left, right, li, rj, valid, capacity
+        )
     return Relation(out_schema, cols, valid), total, total > capacity
 
 
@@ -133,15 +138,18 @@ def matrix_left_join(
     the counts vector directly (counts are already in left buffer order —
     no sort to invert, unlike the MR backend's semijoin scatter-back)."""
     counts, first, b, cl, pos_r = _match_arrays(left, right, use_kernel)
-    li, rj, valid, total = _expand_gather(
-        counts, first, b, cl, pos_r, capacity
-    )
-    out_schema, right_extra, join_cols = _joined_cols(
-        left, right, li, rj, valid, capacity
-    )
-    unmatched = left.valid & (counts == 0)
-    pad = jnp.full((left.capacity, len(right_extra)), UNBOUND, jnp.int32)
-    pad_cols = jnp.concatenate([left.cols, pad], axis=1)
-    cols = jnp.concatenate([join_cols, pad_cols], axis=0)
-    valid_all = jnp.concatenate([valid, unmatched])
+    with jax.named_scope("expand"):
+        li, rj, valid, total = _expand_gather(
+            counts, first, b, cl, pos_r, capacity
+        )
+        out_schema, right_extra, join_cols = _joined_cols(
+            left, right, li, rj, valid, capacity
+        )
+        unmatched = left.valid & (counts == 0)
+        pad = jnp.full(
+            (left.capacity, len(right_extra)), UNBOUND, jnp.int32
+        )
+        pad_cols = jnp.concatenate([left.cols, pad], axis=1)
+        cols = jnp.concatenate([join_cols, pad_cols], axis=0)
+        valid_all = jnp.concatenate([valid, unmatched])
     return Relation(out_schema, cols, valid_all), total, total > capacity

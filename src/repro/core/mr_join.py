@@ -61,15 +61,19 @@ def _map_phase(left: Relation, right: Relation, key_vars: list[str]):
 
 def _sort_count_phase(l_key: jax.Array, r_key: jax.Array) -> JoinPlanArrays:
     """Sort + the counting half of ReduceDuplicate (Mars pass 1)."""
-    order_l = jnp.argsort(l_key)
-    order_r = jnp.argsort(r_key)
-    lk_sorted = l_key[order_l]
-    rk_sorted = r_key[order_r]
-    lo = jnp.searchsorted(rk_sorted, lk_sorted, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(rk_sorted, lk_sorted, side="right").astype(jnp.int32)
-    counts = hi - lo
-    prefix = jnp.cumsum(counts, dtype=jnp.int32)
-    total = prefix[-1] if counts.shape[0] else jnp.int32(0)
+    with jax.named_scope("sort"):
+        order_l = jnp.argsort(l_key)
+        order_r = jnp.argsort(r_key)
+        lk_sorted = l_key[order_l]
+        rk_sorted = r_key[order_r]
+    with jax.named_scope("count"):
+        lo = jnp.searchsorted(
+            rk_sorted, lk_sorted, side="left").astype(jnp.int32)
+        hi = jnp.searchsorted(
+            rk_sorted, lk_sorted, side="right").astype(jnp.int32)
+        counts = hi - lo
+        prefix = jnp.cumsum(counts, dtype=jnp.int32)
+        total = prefix[-1] if counts.shape[0] else jnp.int32(0)
     return JoinPlanArrays(order_l, order_r, lo, counts, prefix, total)
 
 
@@ -110,7 +114,8 @@ def mr_join_plan(left: Relation, right: Relation) -> tuple[JoinPlanArrays, list[
         raise ValueError(
             f"cross join between {left.schema} and {right.schema}; use cross_join()"
         )
-    l_key, r_key = _map_phase(left, right, key_vars)
+    with jax.named_scope("map"):
+        l_key, r_key = _map_phase(left, right, key_vars)
     return _sort_count_phase(l_key, r_key), key_vars
 
 
@@ -134,18 +139,19 @@ def mr_join(
     the eager engine re-runs with a larger capacity (Mars two-pass).
     """
     plan, key_vars = mr_join_plan(left, right)
-    li, rj, valid = expand_pairs(plan, capacity, use_kernel=use_kernel)
-    right_extra = [v for v in right.schema if v not in left.schema]
-    out_schema = tuple(left.schema) + tuple(right_extra)
-    l_cols = left.cols[li]
-    r_cols = (
-        right.project(right_extra).cols[rj]
-        if right_extra
-        else jnp.zeros((capacity, 0), jnp.int32)
-    )
-    cols = jnp.concatenate([l_cols, r_cols], axis=1)
-    cols = jnp.where(valid[:, None], cols, 0)
-    overflowed = plan.total > capacity
+    with jax.named_scope("expand"):
+        li, rj, valid = expand_pairs(plan, capacity, use_kernel=use_kernel)
+        right_extra = [v for v in right.schema if v not in left.schema]
+        out_schema = tuple(left.schema) + tuple(right_extra)
+        l_cols = left.cols[li]
+        r_cols = (
+            right.project(right_extra).cols[rj]
+            if right_extra
+            else jnp.zeros((capacity, 0), jnp.int32)
+        )
+        cols = jnp.concatenate([l_cols, r_cols], axis=1)
+        cols = jnp.where(valid[:, None], cols, 0)
+        overflowed = plan.total > capacity
     return Relation(out_schema, cols, valid), plan.total, overflowed
 
 
@@ -165,25 +171,28 @@ def left_join(
     the bucket the engine calibrates and grows.
     """
     plan, _ = mr_join_plan(left, right)
-    li, rj, valid = expand_pairs(plan, capacity, use_kernel=use_kernel)
-    right_extra = [v for v in right.schema if v not in left.schema]
-    out_schema = tuple(left.schema) + tuple(right_extra)
-    l_cols = left.cols[li]
-    r_cols = (
-        right.project(right_extra).cols[rj]
-        if right_extra
-        else jnp.zeros((capacity, 0), jnp.int32)
-    )
-    join_cols = jnp.where(
-        valid[:, None], jnp.concatenate([l_cols, r_cols], axis=1), 0
-    )
-    # unmatched-left padding (the semijoin mask, inverted)
-    unmatched = left.valid & ~_matched_left_mask(plan, left)
-    pad = jnp.full((left.capacity, len(right_extra)), UNBOUND, jnp.int32)
-    pad_cols = jnp.concatenate([left.cols, pad], axis=1)
-    cols = jnp.concatenate([join_cols, pad_cols], axis=0)
-    valid_all = jnp.concatenate([valid, unmatched])
-    overflowed = plan.total > capacity
+    with jax.named_scope("expand"):
+        li, rj, valid = expand_pairs(plan, capacity, use_kernel=use_kernel)
+        right_extra = [v for v in right.schema if v not in left.schema]
+        out_schema = tuple(left.schema) + tuple(right_extra)
+        l_cols = left.cols[li]
+        r_cols = (
+            right.project(right_extra).cols[rj]
+            if right_extra
+            else jnp.zeros((capacity, 0), jnp.int32)
+        )
+        join_cols = jnp.where(
+            valid[:, None], jnp.concatenate([l_cols, r_cols], axis=1), 0
+        )
+        # unmatched-left padding (the semijoin mask, inverted)
+        unmatched = left.valid & ~_matched_left_mask(plan, left)
+        pad = jnp.full(
+            (left.capacity, len(right_extra)), UNBOUND, jnp.int32
+        )
+        pad_cols = jnp.concatenate([left.cols, pad], axis=1)
+        cols = jnp.concatenate([join_cols, pad_cols], axis=0)
+        valid_all = jnp.concatenate([valid, unmatched])
+        overflowed = plan.total > capacity
     return Relation(out_schema, cols, valid_all), plan.total, overflowed
 
 

@@ -1,7 +1,8 @@
 """Lightweight span tracing for the query path.
 
 One `Trace` per request, a tree of `Span`s under its root covering
-parse -> optimize -> compile -> dispatch -> transfer -> decode. Clocks
+queue_wait -> prepare (parse, optimize) -> batch_wait -> stage ->
+compile -> dispatch -> decode_wait -> transfer -> decode. Clocks
 are monotonic (`time.perf_counter`); a wall-clock epoch captured at
 trace creation anchors the Chrome trace-event export. Everything is
 thread-safe: spans are appended under the trace's lock, because a
@@ -30,15 +31,39 @@ by a shared `dispatch_id` attribute.
 `recent_traces()`) and the slow-query log: traces whose total duration
 crosses `slow_ms` are kept separately with their full span tree and the
 plan signature the engine attached.
+
+Serving phases on the profiler's clock: `phase(tracer, name)` opens a
+`jax.profiler.TraceAnnotation("mapsq.<name>")` on the calling thread
+(batcher: wait, collect, batch, and inside a batch prepare, stage,
+launch, sync; decode worker: transfer, decode), so a device trace names
+what each thread was doing while the device ran or idled. Without a
+tracer it returns one shared no-op context: the untraced path builds no
+annotation and reads no clock.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
-from typing import Any, Iterable
+from typing import Any, ContextManager, Iterable
+
+from jax.profiler import TraceAnnotation
 
 _ids = itertools.count(1)
+
+PHASE_PREFIX = "mapsq."
+_NO_PHASE = contextlib.nullcontext()
+
+
+def phase(tracer: "Tracer | None", name: str,
+          **attrs: Any) -> ContextManager:
+    """The serving phase `name` as a profiler annotation on this thread
+    (`mapsq.<name>`, `attrs` as its stats), or a shared no-op context when
+    there is no tracer."""
+    if tracer is None:
+        return _NO_PHASE
+    return TraceAnnotation(PHASE_PREFIX + name, **attrs)
 
 
 class Span:

@@ -12,15 +12,30 @@ running it inline. With a pool attached, dispatch of batch k+1 overlaps
 decode of batch k and per-request futures resolve from the decode side;
 without one, deferred slots are resolved inline (the synchronous
 pre-pipeline behaviour).
+
+`phase(name)`, when given, returns a context manager the batcher thread
+opens around each of its phases: "wait" (blocked with no request),
+"collect" (the `max_wait_s` window after the first request) and "batch"
+(batch_fn and the hand-off of its results; batch_fn's own phases nest
+inside). The server hands in the tracer's profiler annotation, so this
+module stays free of the obs package; without one the loop opens a
+shared no-op context.
 """
 from __future__ import annotations
 
+import contextlib
 import copy
 import dataclasses
 import queue
 import threading
 import time
-from typing import Any, Callable, Optional
+from typing import Any, Callable, ContextManager, Optional
+
+_NO_PHASE = contextlib.nullcontext()
+
+
+def _no_phase(name: str) -> ContextManager:
+    return _NO_PHASE
 
 
 class BatchTimeout(TimeoutError):
@@ -77,20 +92,24 @@ class Request:
     result: Any = None
     # submitter gave up (deadline expired): decode stages skip the work
     abandoned: bool = False
-    # per-request trace (obs.Trace) or None: the batcher and decode pool
-    # annotate failure paths on it (duck-typed — this module stays
-    # import-free of the obs package)
+    # per-request trace (obs.Trace) or None: the batcher records the
+    # request's `queue_wait` on it and the batcher and decode pool annotate
+    # failure paths (duck-typed — this module stays import-free of the obs
+    # package)
     trace: Any = None
+    t_submit: float = 0.0  # perf_counter at submit, traced requests only
 
 
 class MicroBatcher:
     def __init__(self, batch_fn: Callable[[list[Any]], list[Any]],
                  max_batch: int, max_wait_s: float = 0.005,
-                 decode_pool: Optional[Any] = None):
+                 decode_pool: Optional[Any] = None,
+                 phase: Optional[Callable[[str], ContextManager]] = None):
         self.batch_fn = batch_fn
         self.max_batch = max_batch
         self.max_wait_s = max_wait_s
         self.decode_pool = decode_pool  # serve.decode.DecodePool (or None)
+        self.phase = phase or _no_phase
         self.q: queue.Queue[Request] = queue.Queue()
         self._stop = threading.Event()
         self.t = threading.Thread(target=self._loop, daemon=True)
@@ -109,6 +128,8 @@ class MicroBatcher:
     def submit(self, payload: Any, timeout: float = 30.0,
                trace: Any = None) -> Any:
         r = Request(payload, trace=trace)
+        if trace is not None:
+            r.t_submit = time.perf_counter()
         self.q.put(r)
         if not r.event.wait(timeout):
             r.abandoned = True
@@ -135,46 +156,57 @@ class MicroBatcher:
         r.event.set()
 
     def _loop(self) -> None:
+        phase = self.phase
         while not self._stop.is_set():
-            try:
-                first = self.q.get(timeout=0.05)
-            except queue.Empty:
-                continue
-            batch = [first]
-            deadline = time.time() + self.max_wait_s
-            while len(batch) < self.max_batch:
-                left = deadline - time.time()
-                if left <= 0:
-                    break
+            with phase("wait"):
                 try:
-                    batch.append(self.q.get(timeout=left))
+                    first = self.q.get(timeout=0.05)
                 except queue.Empty:
-                    break
-            t0 = time.perf_counter()
-            try:
-                results = self.batch_fn([r.payload for r in batch])
-            except BaseException as e:  # keep the worker alive: fail the
-                # batch, not the server; independent per-request copies
-                # (original traceback attached) so concurrent re-raises in
-                # client threads never share one instance
-                t1 = time.perf_counter()
-                for r in batch:
-                    if r.trace is not None:
-                        # retroactive (born-closed) span: a failed batch
-                        # leaks nothing even though batch_fn blew up
-                        r.trace.add_span(
-                            "batch_error", t0, t1,
-                            error=type(e).__name__,
-                        )
-                results = [_exc_copy(e) for _ in batch]
-            self.dispatch_s += time.perf_counter() - t0
-            self.n_batches += 1
-            self.n_requests += len(batch)
-            self.batch_size_hist[len(batch)] = (
-                self.batch_size_hist.get(len(batch), 0) + 1
-            )
-            for r, res in zip(batch, results):
-                self._resolve(r, res)
+                    continue
+            batch = [first]
+            with phase("collect"):
+                deadline = time.time() + self.max_wait_s
+                while len(batch) < self.max_batch:
+                    left = deadline - time.time()
+                    if left <= 0:
+                        break
+                    try:
+                        batch.append(self.q.get(timeout=left))
+                    except queue.Empty:
+                        break
+            with phase("batch"):
+                self._run(batch)
+
+    def _run(self, batch: list[Request]) -> None:
+        """One batch: batch_fn, then each request's result handed on."""
+        t0 = time.perf_counter()
+        for r in batch:
+            if r.trace is not None:
+                r.trace.add_span("queue_wait", r.t_submit, t0)
+        try:
+            results = self.batch_fn([r.payload for r in batch])
+        except BaseException as e:  # keep the worker alive: fail the
+            # batch, not the server; independent per-request copies
+            # (original traceback attached) so concurrent re-raises in
+            # client threads never share one instance
+            t1 = time.perf_counter()
+            for r in batch:
+                if r.trace is not None:
+                    # retroactive (born-closed) span: a failed batch
+                    # leaks nothing even though batch_fn blew up
+                    r.trace.add_span(
+                        "batch_error", t0, t1,
+                        error=type(e).__name__,
+                    )
+            results = [_exc_copy(e) for _ in batch]
+        self.dispatch_s += time.perf_counter() - t0
+        self.n_batches += 1
+        self.n_requests += len(batch)
+        self.batch_size_hist[len(batch)] = (
+            self.batch_size_hist.get(len(batch), 0) + 1
+        )
+        for r, res in zip(batch, results):
+            self._resolve(r, res)
 
     def close(self) -> None:
         self._stop.set()

@@ -57,9 +57,11 @@ decode later completes is a timeout, full stop, never also an "ok".
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import OrderedDict
 
+from repro.obs.trace import phase
 from repro.serve.batcher import BatchTimeout, Deferred, MicroBatcher
 from repro.serve.decode import DecodePool
 from repro.sparql.engine import (
@@ -151,9 +153,13 @@ class SPARQLServer:
             DecodePool(self.decode_workers, self.decode_queue)
             if self.decode_workers > 0 else None
         )
-        self._batcher = MicroBatcher(self._run_batch, self.max_batch,
-                                     self.max_wait_s,
-                                     decode_pool=self._decode_pool)
+        tracer = self.engine.tracer
+        self._batcher = MicroBatcher(
+            self._run_batch, self.max_batch, self.max_wait_s,
+            decode_pool=self._decode_pool,
+            phase=(functools.partial(phase, tracer)
+                   if tracer is not None else None),
+        )
         self._prepared: OrderedDict[str, PreparedQuery] = OrderedDict()
         # request-path instruments live on the engine's registry so one
         # render_prometheus() scrape covers both layers; stats() reads the
@@ -285,15 +291,26 @@ class SPARQLServer:
             [None] * len(queries)
         )
         pending: list[tuple[int, "PreparedQuery", bool]] = []
-        for i, text in enumerate(queries):
-            try:
-                pq, cached = self._prepared_handle(text, trace=traces[i])
-            except ParseError as e:
-                outs[i] = ParseQueryError(str(e), query=text)
-            except Exception as e:
-                outs[i] = QueryError("plan", str(e), query=text)
-            else:
-                pending.append((i, pq, cached))
+        traced = any(t is not None for t in traces)
+        t0 = time.perf_counter() if traced else 0.0
+        with phase(self.engine.tracer, "prepare"):
+            for i, text in enumerate(queries):
+                try:
+                    pq, cached = self._prepared_handle(
+                        text, trace=traces[i]
+                    )
+                except ParseError as e:
+                    outs[i] = ParseQueryError(str(e), query=text)
+                except Exception as e:
+                    outs[i] = QueryError("plan", str(e), query=text)
+                else:
+                    pending.append((i, pq, cached))
+        if traced:
+            # the batch's handle lookups (parse + optimize on a miss)
+            t1 = time.perf_counter()
+            for t in traces:
+                if t is not None:
+                    t.add_span("prepare", t0, t1)
         if not pending:
             return outs
         if self.batch_execution:

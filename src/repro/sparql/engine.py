@@ -50,11 +50,13 @@ Two execution modes share one planner:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import pathlib
 import threading
 import time
+import weakref
 from collections import OrderedDict
 
 import numpy as np
@@ -69,6 +71,7 @@ from repro.core import plan_ir
 from repro.core.planner import TriplePattern
 from repro.core.relation import UNBOUND, Relation
 from repro.obs import MetricsRegistry, Tracer
+from repro.obs.trace import phase
 from repro.sparql import algebra, optimizer
 from repro.sparql.parser import Query, UpdateRequest, parse, parse_update
 from repro.sparql.store import TripleStore, _next_pow2
@@ -341,6 +344,48 @@ class ResultSet:
         return f"ResultSet(vars={self.vars}, n_rows={len(self.rows)})"
 
 
+class _Stage:
+    """One dispatch's staging: the store's snapshot lock, the scans staged
+    under it and the runtime-constant upload, inside the `mapsq.stage`
+    annotation when the engine has a tracer. `timed` (a traced request
+    rides the dispatch) also times the interval (`t0`, `t1`) and the wait
+    for the lock (`lock_wait_s`); untimed, it reads no clock."""
+
+    __slots__ = ("_store", "_ann", "timed", "t0", "t1", "lock_wait_s")
+
+    def __init__(self, engine: "QueryEngine", timed: bool):
+        self._store = engine.store
+        self._ann = phase(engine.tracer, "stage")
+        self.timed = timed
+        self.t0 = self.t1 = self.lock_wait_s = 0.0
+
+    def __enter__(self) -> "_Stage":
+        self._ann.__enter__()
+        if self.timed:
+            self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self.timed:
+            self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+
+    @contextlib.contextmanager
+    def locked(self):
+        lock = self._store.snapshot_lock()
+        if not self.timed:
+            with lock:
+                yield
+            return
+        t = time.perf_counter()
+        with lock:
+            self.lock_wait_s += time.perf_counter() - t
+            yield
+
+    def attrs(self) -> dict:
+        return {"lock_wait_ms": round(self.lock_wait_s * 1e3, 3)}
+
+
 class _SharedFetch:
     """One device→host transfer shared by every lane of a stacked chunk.
 
@@ -383,10 +428,13 @@ class PendingDecode:
     `resolve()` (the transfer + row decode + per-handle accounting) runs
     on a decode worker, overlapping the batcher thread's next dispatch.
     `lane` selects this query's slice of a stacked chunk (None for a solo
-    run whose buffers are already 2-D)."""
+    run whose buffers are already 2-D). A traced slot notes when its
+    dispatch finished (`t_ready`): its `decode_wait` span runs from there
+    to the decode worker taking it up — the batch's later groups, the
+    hand-off and the decode pool's queue."""
 
     __slots__ = ("engine", "pq", "vars", "names", "fetch", "lane", "stats",
-                 "trace")
+                 "trace", "t_ready")
 
     def __init__(self, engine: "QueryEngine", pq: "PreparedQuery",
                  vars: tuple[str, ...], names: tuple[str, ...],
@@ -400,16 +448,21 @@ class PendingDecode:
         self.lane = lane
         self.stats = stats
         self.trace = trace
+        self.t_ready = time.perf_counter() if trace is not None else 0.0
 
     def resolve(self) -> ResultSet:
+        tracer = self.engine.tracer
         t0 = time.perf_counter()
-        cols, valid, paid = self.fetch.fetch()
+        with phase(tracer, "transfer"):
+            cols, valid, paid = self.fetch.fetch()
         t1 = time.perf_counter()
-        if self.lane is not None:
-            cols, valid = cols[self.lane], valid[self.lane]
-        rows = self.engine._decode_numpy(self.names, cols[valid])
+        with phase(tracer, "decode"):
+            if self.lane is not None:
+                cols, valid = cols[self.lane], valid[self.lane]
+            rows = self.engine._decode_numpy(self.names, cols[valid])
         t2 = time.perf_counter()
         if self.trace is not None:
+            self.trace.add_span("decode_wait", self.t_ready, t0)
             # the sharing lanes' "transfer" span is their wait on the
             # paying lane's sync (usually ~0): attrs distinguish them
             self.trace.add_span("transfer", t0, t1, paid=paid,
@@ -543,6 +596,10 @@ class QueryEngine:
         self._jit_count = jax.jit(mj.mr_join_count)
         self._jit_cross = jax.jit(mj.cross_join, static_argnames=("capacity",))
         self.plan_cache = PlanCache(self.plan_cache_entries)
+        # executable -> its op_scopes() key (traced launches name it)
+        self._module_keys: weakref.WeakKeyDictionary = (
+            weakref.WeakKeyDictionary()
+        )
         # learned bucket signatures from a previous process: a shape found
         # here compiles directly at the saved capacities, skipping the
         # eager calibration run entirely
@@ -798,6 +855,38 @@ class QueryEngine:
     def cache_stats(self) -> dict:
         return self.plan_cache.stats()
 
+    def op_scopes(self) -> dict[str, dict[str, str]]:
+        """Module key -> {HLO instruction name: plan-operator scope} for
+        every executable in the plan cache (solo and stacked widths):
+        `join2/count` is the count phase of join slot 2 as EXPLAIN ANALYZE
+        numbers slots. A device trace names ops by HLO instruction only;
+        this is what attributes them to plan operators. An executable that
+        a build without scopes compiled (a persistent-cache hit) maps every
+        instruction to ''."""
+        return {
+            self._module_key(c.executable): ex.hlo_op_scopes(
+                c.executable.as_text())
+            for e in self.plan_cache.entries()
+            for c in (e.compiled, *e.batched.values())
+        }
+
+    def _module_key(self, executable) -> str:
+        """`ex.module_key`, once per executable: reading it deserializes the
+        module, tens of ms for a large program on the TPU."""
+        key = self._module_keys.get(executable)
+        if key is None:
+            key = self._module_keys[executable] = ex.module_key(executable)
+        return key
+
+    def _launch(self, compiled) -> "contextlib.AbstractContextManager":
+        """The `mapsq.launch` phase of one executable call, naming the
+        module it launches (`module_key`): what ties a device trace's "XLA
+        Modules" event to its executable's `op_scopes()`."""
+        if self.tracer is None:
+            return phase(None, "launch")
+        return phase(self.tracer, "launch",
+                     module=self._module_key(compiled.executable))
+
     def stats(self) -> dict:
         """One observability snapshot: plan cache, scan cache, and the
         store's write-path health (version, tail size, tombstone count,
@@ -855,14 +944,40 @@ class QueryEngine:
         out: list = [None] * len(prepared)
         if traces is None:
             traces = [None] * len(prepared)
+        # a traced read's `batch_wait` runs from here to its own staging:
+        # the batch's grouping and the groups dispatched before its own
+        t_batch = (
+            time.perf_counter() if any(t is not None for t in traces)
+            else None
+        )
         if not self.compiled:
             group = BatchGroupStats(n_queries=len(prepared), fallback=True)
             self.last_batch.append(group)
             for i, pq in enumerate(prepared):
+                self._note_batch_wait(traces, [i], t_batch)
                 out[i] = self._run_single(pq, group, defer, traces[i])
             return out
-        # group by compiled plan signature (the PlanShape cache key)
         ctxs: list[_BatchCtx | None] = [None] * len(prepared)
+        with phase(self.tracer, "prepare"):
+            merged = self._group_batch(prepared, out, ctxs)
+        for shape, (idxs, n_shapes, n_compiles) in merged.items():
+            self._run_group(
+                shape, idxs, ctxs, prepared, out, defer,
+                n_shapes=n_shapes, extra_compiles=n_compiles,
+                traces=traces, t_batch=t_batch,
+            )
+        return out
+
+    def _group_batch(
+        self,
+        prepared: list[PreparedQuery],
+        out: list,
+        ctxs: list["_BatchCtx | None"],
+    ) -> "OrderedDict[plan_ir.PlanShape, tuple[list[int], int, int]]":
+        """Fill each handle's batch context into `ctxs` and group the batch
+        by compiled plan signature (the PlanShape cache key), merging
+        near-miss shapes when padded stacking is on; a handle whose
+        staging fails gets its exception in `out`."""
         groups: OrderedDict[plan_ir.PlanShape, list[int]] = OrderedDict()
         for i, pq in enumerate(prepared):
             try:
@@ -880,20 +995,22 @@ class QueryEngine:
                 out[i] = e
                 continue
             groups.setdefault(ctxs[i].shape, []).append(i)
-        merged: OrderedDict[plan_ir.PlanShape, tuple[list[int], int, int]]
         if self.pad_stacking and len(groups) > 1:
-            merged = self._coalesce_groups(groups)
-        else:
-            merged = OrderedDict(
-                (s, (idxs, 1, 0)) for s, idxs in groups.items()
-            )
-        for shape, (idxs, n_shapes, n_compiles) in merged.items():
-            self._run_group(
-                shape, idxs, ctxs, prepared, out, defer,
-                n_shapes=n_shapes, extra_compiles=n_compiles,
-                traces=traces,
-            )
-        return out
+            return self._coalesce_groups(groups)
+        return OrderedDict((s, (idxs, 1, 0)) for s, idxs in groups.items())
+
+    @staticmethod
+    def _note_batch_wait(
+        traces: list, idxs: list[int], t_batch: "float | None"
+    ) -> None:
+        """Record `batch_wait` on the traced reads about to run: from the
+        batch's start to now."""
+        if t_batch is None:
+            return
+        now = time.perf_counter()
+        for i in idxs:
+            if traces[i] is not None:
+                traces[i].add_span("batch_wait", t_batch, now)
 
     def _template_scans(
         self, shape: plan_ir.PlanShape
@@ -1031,6 +1148,7 @@ class QueryEngine:
         n_shapes: int = 1,
         extra_compiles: int = 0,
         traces: "list | None" = None,
+        t_batch: "float | None" = None,
     ) -> None:
         if traces is None:
             traces = [None] * len(out)
@@ -1046,6 +1164,7 @@ class QueryEngine:
             # cold shape: the first query runs the normal path (calibration
             # or warmup compile), populating the cache the rest stack on
             group.cold = True
+            self._note_batch_wait(traces, idxs[:1], t_batch)
             out[idxs[0]] = self._run_single(
                 prepared[idxs[0]], group, defer, traces[idxs[0]]
             )
@@ -1058,8 +1177,12 @@ class QueryEngine:
             pos += len(chunk)
             if len(chunk) < 2 or self.plan_cache.get(shape) is None:
                 for i in chunk:
-                    out[i] = self._run_single(prepared[i], group, defer)
+                    self._note_batch_wait(traces, [i], t_batch)
+                    out[i] = self._run_single(
+                        prepared[i], group, defer, traces[i]
+                    )
                 continue
+            self._note_batch_wait(traces, chunk, t_batch)
             try:
                 self._run_chunk_stacked(
                     shape, chunk, ctxs, prepared, out, group, defer, traces
@@ -1104,34 +1227,44 @@ class QueryEngine:
         # staging W stacked copies
         scans_b: list[Relation] = []
         axes: list[int | None] = []
-        with self.store.snapshot_lock():  # one store version per chunk
-            for j in range(len(shape.scan_schemas)):
-                cap = shape.scan_caps[j]
-                tps = tuple(c.prog.patterns[j] for c in lanes)
-                rel = None
-                if len({self.store._scan_key(tp) for tp in tps}) == 1:
-                    rel = self.store.match_pattern_device(tps[0])
-                if rel is not None and rel.capacity == cap:
-                    scans_b.append(
-                        Relation(shape.scan_schemas[j], rel.cols, rel.valid)
-                    )
-                    axes.append(None)
-                else:
-                    scans_b.append(
-                        Relation(
+        timed = traces is not None and any(
+            traces[i] is not None for i in chunk
+        )
+        with _Stage(self, timed) as stg:
+            with stg.locked():  # one store version per chunk
+                for j in range(len(shape.scan_schemas)):
+                    cap = shape.scan_caps[j]
+                    tps = tuple(c.prog.patterns[j] for c in lanes)
+                    rel = None
+                    if len({self.store._scan_key(tp) for tp in tps}) == 1:
+                        rel = self.store.match_pattern_device(tps[0])
+                    if rel is not None and rel.capacity == cap:
+                        scans_b.append(Relation(
+                            shape.scan_schemas[j], rel.cols, rel.valid
+                        ))
+                        axes.append(None)
+                    else:
+                        scans_b.append(Relation(
                             shape.scan_schemas[j],
                             *self.store.stacked_scan_device(tps, cap=cap),
-                        )
-                    )
-                    axes.append(0)
-            staged_version = self.store.version
-        scans_b = tuple(scans_b)
-        scan_axes = tuple(axes)
+                        ))
+                        axes.append(0)
+                staged_version = self.store.version
+            scans_b = tuple(scans_b)
+            scan_axes = tuple(axes)
+            consts_i = jnp.asarray(
+                np.stack([c.prog.consts_i for c in lanes]))
+            consts_f = jnp.asarray(
+                np.stack([c.prog.consts_f for c in lanes]))
+            active = jnp.asarray(np.arange(width) < n)
+            num_vals = self.store.numeric_values_device()
+        # retroactive span intervals, fanned out to every lane trace after
+        # the chunk succeeds (one device launch -> N lane spans correlated
+        # by a shared dispatch_id)
+        events: list[tuple[str, float, float, dict]] = [
+            ("stage", stg.t0, stg.t1, stg.attrs())
+        ]
         group.n_broadcast_scans += sum(1 for a in scan_axes if a is None)
-        consts_i = jnp.asarray(np.stack([c.prog.consts_i for c in lanes]))
-        consts_f = jnp.asarray(np.stack([c.prog.consts_f for c in lanes]))
-        active = jnp.asarray(np.arange(width) < n)
-        num_vals = self.store.numeric_values_device()
         stats = ExecStats(
             n_joins=shape.n_joins(),
             cache_hits=1,
@@ -1148,10 +1281,6 @@ class QueryEngine:
                 shape, entry.join_caps, self._template_scans(shape), None,
                 stats,
             )
-        # retroactive span intervals, fanned out to every lane trace after
-        # the chunk succeeds (one device launch -> N lane "dispatch" spans
-        # correlated by a shared dispatch_id)
-        events: list[tuple[str, float, float]] = []
         ovf_counts = [0] * shape.n_joins()
         try:
             while True:
@@ -1168,17 +1297,21 @@ class QueryEngine:
                         use_kernel=self.use_kernel,
                         scan_axes=scan_axes,
                     )
-                    events.append(("compile", tc0, time.perf_counter()))
+                    events.append(
+                        ("compile", tc0, time.perf_counter(), {}))
                     entry.batched[(width, scan_axes)] = bexec
                     stats.n_compiles += 1
                     self.plan_cache.compiles += 1
                 stats.n_dispatches += 1
                 t0 = time.perf_counter()
-                rel_b, totals_b, flags_b = bexec(
-                    scans_b, consts_i, consts_f, num_vals, active
-                )
-                flags_np = np.asarray(flags_b)  # the single host sync
-                events.append(("dispatch", t0, self._device_tick(stats, t0)))
+                with self._launch(bexec):
+                    rel_b, totals_b, flags_b = bexec(
+                        scans_b, consts_i, consts_f, num_vals, active
+                    )
+                with phase(self.tracer, "sync"):
+                    flags_np = np.asarray(flags_b)  # the single host sync
+                    t1 = self._device_tick(stats, t0)
+                events.append(("dispatch", t0, t1, {}))
                 if not flags_np.any():
                     break
                 # some lane overflowed a bucket: grow each flagged join to
@@ -1272,11 +1405,12 @@ class QueryEngine:
                 st.device_time_s = stats.device_time_s / len(chunk)
             trace = traces[i] if traces is not None else None
             if trace is not None and events:
-                for name, t0, t1 in events:
+                for name, t0, t1, attrs in events:
                     trace.add_span(
                         name, t0, t1,
                         dispatch_id=self._dispatch_seq,
                         width=stats.batch_width, stacked=True, lane=k,
+                        **attrs,
                     )
             pending = PendingDecode(
                 self, prepared[i], names, names, fetch, k, st, trace,
@@ -1420,11 +1554,14 @@ class QueryEngine:
     ) -> Relation:
         if self.compiled:
             return self._execute_compiled(prog, stats, trace)
-        with self.store.snapshot_lock():  # consistent version across scans
+        with _Stage(self, trace is not None) as stg, stg.locked():
+            # one consistent store version across the scans
             scans = tuple(
                 self.store.match_pattern(tp) for tp in prog.patterns
             )
             stats.store_version = self.store.version
+        if trace is not None:
+            trace.add_span("stage", stg.t0, stg.t1, **stg.attrs())
         shape = self._shape_for(
             prog,
             tuple(s.schema for s in scans),
@@ -1671,11 +1808,14 @@ class QueryEngine:
     def _execute_compiled(
         self, prog: _Program, stats: ExecStats, trace=None
     ) -> Relation:
-        with self.store.snapshot_lock():
-            canon_scans, shape, inverse = self._canonicalize(prog)
-            stats.store_version = self.store.version
-        stats.n_joins = shape.n_joins()
-        consts_i, consts_f, num_vals = self._device_consts(prog)
+        with _Stage(self, trace is not None) as stg:
+            with stg.locked():
+                canon_scans, shape, inverse = self._canonicalize(prog)
+                stats.store_version = self.store.version
+            stats.n_joins = shape.n_joins()
+            consts_i, consts_f, num_vals = self._device_consts(prog)
+        if trace is not None:
+            trace.add_span("stage", stg.t0, stg.t1, **stg.attrs())
 
         entry = self.plan_cache.get(shape)
         if entry is not None and entry.num_cap not in (
@@ -1788,9 +1928,10 @@ class QueryEngine:
         while True:
             stats.n_dispatches += 1
             t0 = time.perf_counter()
-            rel, totals, flags = entry.compiled(
-                canon_scans, consts_i, consts_f, num_vals
-            )
+            with self._launch(entry.compiled):
+                rel, totals, flags = entry.compiled(
+                    canon_scans, consts_i, consts_f, num_vals
+                )
             stats.peak_capacity = max(
                 stats.peak_capacity, entry.compiled.plan.max_capacity()
             )
@@ -1798,14 +1939,14 @@ class QueryEngine:
             stats.peak_join_bucket = max(
                 stats.peak_join_bucket, max(caps) if caps else 0
             )
-            flags_np = np.asarray(flags)  # the single host sync
-            t1 = self._device_tick(stats, t0)
+            with phase(self.tracer, "sync"):
+                flags_np = np.asarray(flags)  # the single host sync
+                t1 = self._device_tick(stats, t0)
+                totals_np = np.asarray(totals)
             if trace is not None:
                 trace.add_span("dispatch", t0, t1)
             if not flags_np.any():
-                stats.join_totals = tuple(
-                    int(t) for t in np.asarray(totals)
-                )
+                stats.join_totals = tuple(int(t) for t in totals_np)
                 stats.join_worst = stats.join_totals
                 stats.join_caps = tuple(caps)
                 stats.join_overflows = tuple(ovf_counts)
@@ -1816,7 +1957,7 @@ class QueryEngine:
                 ovf_counts[j] += int(bool(f))
             new_caps = plan_ir.grow_join_caps(
                 entry.join_caps,
-                [int(t) for t in np.asarray(totals)],
+                [int(t) for t in totals_np],
                 [bool(f) for f in flags_np],
             )
             if max(new_caps) > self.max_capacity:
@@ -2427,7 +2568,10 @@ class ShardedQueryEngine(QueryEngine):
             stats.n_dispatches += 1
             self._count_shuffles(entry, stats)
             t0 = time.perf_counter()
-            res = entry.compiled(canon_scans, consts_i, consts_f, num_vals)
+            with self._launch(entry.compiled):
+                res = entry.compiled(
+                    canon_scans, consts_i, consts_f, num_vals
+                )
             caps = entry.compiled.plan.join_caps
             stats.peak_capacity = max(
                 stats.peak_capacity, entry.compiled.plan.max_capacity()
@@ -2435,10 +2579,11 @@ class ShardedQueryEngine(QueryEngine):
             stats.peak_join_bucket = max(
                 stats.peak_join_bucket, max(caps) if caps else 0
             )
-            # the single host sync: join AND shuffle flags, all shards
-            flags_np = np.asarray(res.overflows)
-            sh_flags_np = np.asarray(res.shuffle_flags)
-            t1 = self._device_tick(stats, t0)
+            with phase(self.tracer, "sync"):
+                # the single host sync: join AND shuffle flags, all shards
+                flags_np = np.asarray(res.overflows)
+                sh_flags_np = np.asarray(res.shuffle_flags)
+                t1 = self._device_tick(stats, t0)
             if trace is not None:
                 trace.add_span(
                     "dispatch", t0, t1, n_shards=self.n_shards
@@ -2532,35 +2677,40 @@ class ShardedQueryEngine(QueryEngine):
         # vmap splits lanes (dim 0)
         scans_b: list[Relation] = []
         axes: list[int | None] = []
-        with self.store.snapshot_lock():  # one store version per chunk
-            for j in range(len(shape.scan_schemas)):
-                tps = tuple(c.prog.patterns[j] for c in lanes)
-                if len({self.store._scan_key(tp) for tp in tps}) == 1:
-                    rel = self.store.match_pattern_device(tps[0])
-                    scans_b.append(
-                        Relation(shape.scan_schemas[j], rel.cols, rel.valid)
-                    )
-                    axes.append(None)
-                else:
-                    scans_b.append(
-                        Relation(
+        timed = traces is not None and any(
+            traces[i] is not None for i in chunk
+        )
+        with _Stage(self, timed) as stg:
+            with stg.locked():  # one store version per chunk
+                for j in range(len(shape.scan_schemas)):
+                    tps = tuple(c.prog.patterns[j] for c in lanes)
+                    if len({self.store._scan_key(tp) for tp in tps}) == 1:
+                        rel = self.store.match_pattern_device(tps[0])
+                        scans_b.append(Relation(
+                            shape.scan_schemas[j], rel.cols, rel.valid
+                        ))
+                        axes.append(None)
+                    else:
+                        scans_b.append(Relation(
                             shape.scan_schemas[j],
                             *self.store.stacked_scan_device(tps),
-                        )
-                    )
-                    axes.append(0)
-            staged_version = self.store.version
-        scans_b = tuple(scans_b)
-        scan_axes = tuple(axes)
+                        ))
+                        axes.append(0)
+                staged_version = self.store.version
+            scans_b = tuple(scans_b)
+            scan_axes = tuple(axes)
+            consts_i = self._replicated(
+                np.stack([c.prog.consts_i for c in lanes])
+            )
+            consts_f = self._replicated(
+                np.stack([c.prog.consts_f for c in lanes])
+            )
+            active = self._replicated(np.arange(width) < n)
+            num_vals = self._num_vals()
+        events: list[tuple[str, float, float, dict]] = [
+            ("stage", stg.t0, stg.t1, stg.attrs())
+        ]
         group.n_broadcast_scans += sum(1 for a in scan_axes if a is None)
-        consts_i = self._replicated(
-            np.stack([c.prog.consts_i for c in lanes])
-        )
-        consts_f = self._replicated(
-            np.stack([c.prog.consts_f for c in lanes])
-        )
-        active = self._replicated(np.arange(width) < n)
-        num_vals = self._num_vals()
         stats = ExecStats(
             n_joins=shape.n_joins(),
             cache_hits=1,
@@ -2573,7 +2723,6 @@ class ShardedQueryEngine(QueryEngine):
             entry = self._compile_entry(
                 shape, entry.join_caps, template_scans, None, stats
             )
-        events: list[tuple[str, float, float]] = []
         ovf_counts = [0] * shape.n_joins()
         try:
             while True:
@@ -2593,21 +2742,25 @@ class ShardedQueryEngine(QueryEngine):
                         scan_axes,
                         use_kernel=self.use_kernel,
                     )
-                    events.append(("compile", tc0, time.perf_counter()))
+                    events.append(
+                        ("compile", tc0, time.perf_counter(), {}))
                     entry.batched[(width, scan_axes)] = bexec
                     stats.n_compiles += 1
                     self.plan_cache.compiles += 1
                 stats.n_dispatches += 1
                 self._count_shuffles(entry, stats)
                 t0 = time.perf_counter()
-                res = bexec(scans_b, consts_i, consts_f, num_vals, active)
-                # the single host sync: join AND shuffle flags, every
-                # (lane, shard) pair
-                flags_np = np.asarray(res.overflows)
-                sh_flags_np = np.asarray(res.shuffle_flags)
-                events.append(
-                    ("dispatch", t0, self._device_tick(stats, t0))
-                )
+                with self._launch(bexec):
+                    res = bexec(
+                        scans_b, consts_i, consts_f, num_vals, active
+                    )
+                with phase(self.tracer, "sync"):
+                    # the single host sync: join AND shuffle flags, every
+                    # (lane, shard) pair
+                    flags_np = np.asarray(res.overflows)
+                    sh_flags_np = np.asarray(res.shuffle_flags)
+                    t1 = self._device_tick(stats, t0)
+                events.append(("dispatch", t0, t1, {}))
                 if not flags_np.any() and not sh_flags_np.any():
                     break
                 # a bucket overflowed in some lane on some shard: grow the
