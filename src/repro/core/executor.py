@@ -237,7 +237,7 @@ def op_scope(node: PlanNode, slot_of: dict[int, int]) -> str:
 
 def scope_of(op_name: str) -> str:
     """The plan-operator scope inside an HLO `op_name`:
-    'jit(run)/join2/count/jit(searchsorted)/while' -> 'join2/count';
+    'jit(run)/join2/expand/jit(searchsorted)/while' -> 'join2/expand';
     transform wrappers ('vmap(join0)', 'vmap()', 'shard_map') are looked
     through, and the last part, the primitive's own name, is never a
     scope. '' when the op sits under no plan operator."""

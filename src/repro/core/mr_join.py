@@ -6,14 +6,18 @@ Three phases, exactly as the paper structures them:
                     (padding) rows are mapped to per-side sentinel keys so
                     they can never join (the LEFT/RIGHT flag's purpose —
                     "reduce unnecessary computation" — achieved structurally).
-  Sort            — sort both sides by key (the shuffle). On TPU this is a
-                    bitonic network (see kernels/bitonic_sort); here we use
-                    XLA's sort, which lowers to the same thing.
+  Sort            — sort both sides by key together (the shuffle): one
+                    co-sort of the key columns, ties broken by row so left
+                    rows come first on equal keys. On TPU this is a bitonic
+                    network (see kernels/bitonic_sort); here we use XLA's
+                    sort, which lowers to the same thing.
   ReduceDuplicate — per key group, emit the cartesian product of LEFT values
                     with RIGHT values. Realised as: per-left-row match counts
-                    via binary search, prefix sum, then a dense inverse-
-                    prefix-sum gather (kernels/pair_expand) — one output
-                    element per lane, perfectly load balanced.
+                    read off the co-sorted order (right rows before the row,
+                    and right rows up to its key group's end), prefix sum,
+                    then a dense inverse-prefix-sum gather
+                    (kernels/pair_expand) — one output element per lane,
+                    perfectly load balanced.
 
 Dynamic result size is handled Mars-style: a count pass returns the exact
 total; the expand pass fills a static-capacity buffer with a validity mask.
@@ -24,6 +28,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.relation import (
     INVALID_LEFT,
@@ -33,6 +38,13 @@ from repro.core.relation import (
     shared_vars,
 )
 from repro.core.segments import dense_rank_two_sided
+
+
+# How `_sort_count_phase` counts matches, as EXPLAIN names it: one sort of
+# both sides (it beat two binary searches per left row at every join shape
+# of the LUBM(20) benchmark on a v5e chip, 64 to 65,536 left rows against
+# 512 to 2^20 right rows, solo and vmapped; benchmarks/bench_join_count.py).
+COUNT_METHOD = "co-sort"
 
 
 class JoinPlanArrays(NamedTuple):
@@ -46,34 +58,104 @@ class JoinPlanArrays(NamedTuple):
     total: jax.Array  # () int32 exact number of join results
 
 
-def _map_phase(left: Relation, right: Relation, key_vars: list[str]):
-    """Map: extract key columns, tag sides via sentinels on invalid rows."""
+def _key_columns(left: Relation, right: Relation, key_vars: list[str]):
+    """Map: the (n, k) key columns of each side, invalid rows set to
+    per-side sentinels."""
     lk = jnp.stack([left.column(v) for v in key_vars], axis=1)
     rk = jnp.stack([right.column(v) for v in key_vars], axis=1)
     lk = jnp.where(left.valid[:, None], lk, INVALID_LEFT)
     rk = jnp.where(right.valid[:, None], rk, INVALID_RIGHT)
+    return lk, rk
+
+
+def _map_phase(left: Relation, right: Relation, key_vars: list[str]):
+    """Map to one int32 key per row (the matrix backend's form)."""
+    lk, rk = _key_columns(left, right, key_vars)
     if len(key_vars) == 1:
         return lk[:, 0], rk[:, 0]
-    # Multi-variable join: dense-rank tuples jointly so binary search works
-    # on a single int32 key. Sentinel rows keep never-equal ranks.
+    # Multi-variable join: dense-rank tuples jointly into a single int32
+    # key. Sentinel rows keep never-equal ranks.
     return dense_rank_two_sided(lk, rk)
 
 
-def _sort_count_phase(l_key: jax.Array, r_key: jax.Array) -> JoinPlanArrays:
-    """Sort + the counting half of ReduceDuplicate (Mars pass 1)."""
+def _sort(operands: tuple, num_keys: int) -> tuple:
+    """`lax.sort` by the first `num_keys` operands, the last of which is
+    unique, so no stability is needed. Under vmap the lanes are sorted as
+    one array, lane id first: XLA's TPU sort of a batch of rows runs
+    several times slower than one sort of all of them."""
+
+    @jax.custom_batching.custom_vmap
+    def sort(*ops):
+        return tuple(lax.sort(ops, num_keys=num_keys, is_stable=False))
+
+    @sort.def_vmap
+    def _(axis_size, in_batched, *ops):
+        ops = [o if b else jnp.broadcast_to(o, (axis_size, *o.shape))
+               for o, b in zip(ops, in_batched)]
+        n = ops[0].shape[1]
+        lane = jnp.repeat(jnp.arange(axis_size, dtype=jnp.int32), n)
+        out = _sort((lane, *(o.reshape(-1) for o in ops)), num_keys + 1)
+        out = tuple(o.reshape(axis_size, n) for o in out[1:])
+        return out, (True,) * len(out)
+
+    return sort(*operands)
+
+
+_SCAN_BLOCK = 1024
+
+
+def _scan(cum, op, x: jax.Array, fill, reverse: bool = False) -> jax.Array:
+    """Inclusive scan `cum` (op, identity `fill`) of a 1-D array, as scans
+    of 1024-row blocks and of the block totals. Same numbers as one scan;
+    XLA's TPU compiler takes tens of seconds over a scan of a million rows
+    and about a second over this."""
+    n = x.shape[0]
+    m = jnp.pad(x, (0, -n % _SCAN_BLOCK), constant_values=fill)
+    m = cum(m.reshape(-1, _SCAN_BLOCK), axis=1, reverse=reverse)
+    total = m[:, 0] if reverse else m[:, -1]
+    carry = cum(total, reverse=reverse)  # through each block, inclusive
+    fill_1 = jnp.full(1, fill, x.dtype)
+    carry = (jnp.concatenate([carry[1:], fill_1]) if reverse
+             else jnp.concatenate([fill_1, carry[:-1]]))
+    return op(m, carry[:, None]).reshape(-1)[:n]
+
+
+def _sort_count_phase(lk: jax.Array, rk: jax.Array) -> JoinPlanArrays:
+    """Sort + the counting half of ReduceDuplicate (Mars pass 1).
+
+    One sort of both sides' key columns together, ties broken by row with
+    left rows first: a left row's first match is the number of right rows
+    before it, and its match count runs to the right rows counted at its
+    key group's end. Every field equals what two binary searches of the
+    sorted left keys in the sorted right keys give, with no search loop.
+    """
+    n_l, n_r = lk.shape[0], rk.shape[0]
+    n = n_l + n_r
+    row = jnp.arange(n, dtype=jnp.int32)
     with jax.named_scope("sort"):
-        order_l = jnp.argsort(l_key)
-        order_r = jnp.argsort(r_key)
-        lk_sorted = l_key[order_l]
-        rk_sorted = r_key[order_r]
+        cols = [jnp.concatenate([lk[:, c], rk[:, c]])
+                for c in range(lk.shape[1])]
+        *s_keys, s_row = _sort((*cols, row), len(cols) + 1)
     with jax.named_scope("count"):
-        lo = jnp.searchsorted(
-            rk_sorted, lk_sorted, side="left").astype(jnp.int32)
-        hi = jnp.searchsorted(
-            rk_sorted, lk_sorted, side="right").astype(jnp.int32)
-        counts = hi - lo
+        is_r = (s_row >= n_l).astype(jnp.int32)
+        r_incl = _scan(lax.cumsum, jnp.add, is_r, 0)
+        before = r_incl - is_r
+        # the last row of each key group (the array's last row either way:
+        # past the last group end, n_r is the count)
+        group_end = jnp.stack(
+            [c != jnp.roll(c, -1) for c in s_keys]).any(axis=0)
+        upto = _scan(lax.cummin, jnp.minimum,
+                     jnp.where(group_end, r_incl, n_r), n_r, reverse=True)
+    with jax.named_scope("sort"):
+        # back to sorted-left, then sorted-right order: a left row's place
+        # is its merged position less the right rows before it
+        dest = jnp.where(is_r == 1, n_l + before, row - before)
+        _, order, lo, counts = _sort((dest, s_row, before, upto - before), 1)
+        order_l, order_r = order[:n_l], order[n_l:] - n_l
+        lo, counts = lo[:n_l], counts[:n_l]
+    with jax.named_scope("count"):
         prefix = jnp.cumsum(counts, dtype=jnp.int32)
-        total = prefix[-1] if counts.shape[0] else jnp.int32(0)
+        total = prefix[-1] if n_l else jnp.int32(0)
     return JoinPlanArrays(order_l, order_r, lo, counts, prefix, total)
 
 
@@ -115,8 +197,8 @@ def mr_join_plan(left: Relation, right: Relation) -> tuple[JoinPlanArrays, list[
             f"cross join between {left.schema} and {right.schema}; use cross_join()"
         )
     with jax.named_scope("map"):
-        l_key, r_key = _map_phase(left, right, key_vars)
-    return _sort_count_phase(l_key, r_key), key_vars
+        lk, rk = _key_columns(left, right, key_vars)
+    return _sort_count_phase(lk, rk), key_vars
 
 
 def mr_join_count(left: Relation, right: Relation) -> jax.Array:

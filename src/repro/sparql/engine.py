@@ -2125,10 +2125,11 @@ class QueryEngine:
             return out
 
         def bk() -> str:
-            """Physical algebra of the CURRENT join slot (pre-est_str)."""
+            """Physical algebra of the CURRENT join slot (pre-est_str),
+            with the MR join's count method."""
             if ji < len(backends) and backends[ji] == "matrix":
                 return "matrix_join"
-            return "mr_join"
+            return f"mr_join count={mj.COUNT_METHOD}"
 
         for i, is_cross in enumerate(shape.cross_flags):
             kind = "cross_join" if is_cross else bk()

@@ -565,13 +565,16 @@ def test_optimizer_routes_skewed_join_to_matrix_backend():
 
 def test_uniform_joins_stay_on_mr_backend():
     """Plain LUBM joins have no hot key: every slot keeps the MR backend
-    and explain() renders mr_join."""
+    and explain() renders mr_join with its count method."""
     store = lubm.generate(scale=1, seed=0)
     eng = QueryEngine(store)
     for name in ("Q2", "Q9"):
         prog = eng._build_program(eng.prepare(lubm.QUERIES[name]).query)
         assert set(prog.plan.join_backends) <= {"mr"}, name
-        assert "matrix_join" not in eng.explain(lubm.QUERIES[name])
+        text = eng.explain(lubm.QUERIES[name])
+        assert "matrix_join" not in text
+        joins = [ln for ln in text.splitlines() if ln.startswith("  join[")]
+        assert joins and all("mr_join count=co-sort" in ln for ln in joins)
 
 
 @pytest.mark.parametrize("n_left,n_right,want", [
