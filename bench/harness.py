@@ -3,6 +3,9 @@
 The cell's name is looked up in BENCHMARK.json; its configuration file,
 its traffic file and each metric's reader (bench/metrics/<name>.py) are
 found by name, so a new cell, mix or metric is new files and entries.
+A configuration's `layout` names the store layout: without it one
+TripleStore serves from one chip; `{"partition": "subject_hash"}` shards
+it over every chip of the cell under one ShardedQueryEngine.
 """
 from __future__ import annotations
 
@@ -108,6 +111,28 @@ class Ctx:
     device_kind: str
     least_bytes: dict  # read text -> least bytes a device must move
     peaks: dict
+    phases: object = None  # phases.Phases, traced runs
+
+
+LAYOUTS = ("subject_hash",)
+
+
+def store_layout(config: dict, chips: int) -> str | None:
+    """The store layout a configuration names (`"layout": {"partition":
+    ...}`), checked against the cell's chips: None is one TripleStore on
+    one chip; "subject_hash" shards the store by subject over every chip
+    of the cell. A layout that would leave a chip of the cell idle, or
+    shard over one chip, is refused."""
+    part = (config.get("layout") or {}).get("partition")
+    if part is not None and part not in LAYOUTS:
+        raise ValueError(f"unknown store layout {part!r}; known: {LAYOUTS}")
+    if part is None and chips != 1:
+        raise ValueError(f"a cell of {chips} chips names no store layout: "
+                         f"one TripleStore would leave {chips - 1} idle")
+    if part is not None and chips < 2:
+        raise ValueError(f"the {part} layout needs a cell of more than one "
+                         f"chip, not {chips}")
+    return part
 
 
 def _signature(model: check.Model, text: str) -> tuple:
@@ -233,6 +258,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         config = {**config, **config_override}
     if traffic_override:
         traffic = {**traffic, **traffic_override}
+    chips = int(w["chips"])
+    partition = store_layout(config, chips)
     seed = int(seed) % (1 << 63)
     os.makedirs(cache_root, exist_ok=True)
     cache_dir = enable_compile_cache(cache_root)
@@ -242,10 +269,11 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     dev = devices[0]
     log(f"jax {jax.__version__}: platform={dev.platform} "
         f"device_kind={dev.device_kind} devices={len(devices)}")
-    if require_platform and (dev.platform != require_platform
-                             or len(devices) < int(w["chips"])):
-        raise NoChip(f"cell {workload} needs {w['chips']} {require_platform} "
+    if ((require_platform and dev.platform != require_platform)
+            or len(devices) < chips):
+        raise NoChip(f"cell {workload} needs {chips} {require_platform} "
                      f"chip(s); jax found {len(devices)} {dev.platform}")
+    cell_devices = devices[:chips]
     sys.path.insert(0, os.path.join(ROOT, "src"))
     from repro.obs.trace import Tracer
     from repro.serve.sparql_server import SPARQLServer
@@ -270,9 +298,24 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                              int(st_cfg.get("live_inserted", 0)))
     model = check.Model(data, sched.all_students())
     plans = os.path.join(cache_root, f"{workload}.plans.json")
-    engine = QueryEngine(
-        store, warmup_path=plans if os.path.exists(plans) else None,
-        tracer=Tracer(ring_size=1 << 20) if trace else None)
+    engine_kw = dict(warmup_path=plans if os.path.exists(plans) else None,
+                     tracer=Tracer(ring_size=1 << 20) if trace else None)
+    if partition is None:
+        engine = QueryEngine(store, **engine_kw)
+    else:
+        from repro.core import compat
+        from repro.sparql.engine import ShardedQueryEngine
+        from repro.sparql.sharded_store import shard_store
+
+        t0 = time.perf_counter()
+        store = shard_store(store, chips)
+        log(f"store: {partition} over {chips} chips in "
+            f"{time.perf_counter() - t0:.3f} s, shard sizes "
+            f"{store.shard_sizes()}")
+        engine = ShardedQueryEngine(
+            store, mesh=compat.make_mesh((chips,), ("shards",),
+                                         devices=cell_devices),
+            **engine_kw)
     # The window's server starts with an empty prepared-statement cache:
     # set-up warms the engine's compiled shapes through a server of its
     # own, and the window's reads parse and plan as a fresh server would.
@@ -336,19 +379,26 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             f"store {store.write_stats()}")
         if compactor is not None:
             compactor.close()
-        reduced = None
+        reduced = phased = None
         if trace:
             jax.profiler.stop_trace()
+            import phases
             import xplane
 
             path = xplane.find_xplane(trace_dir)
-            reduced = xplane.reduce_file(path) if path else None
+            if path:
+                planes = phases.load(path)
+                reduced = xplane.reduce_planes(planes)
+                phased = phases.reduce_planes(planes, engine.op_scopes())
+                del planes
             shutil.rmtree(trace_dir, ignore_errors=True)
             if reduced is None and require_platform:
                 raise RuntimeError("the profiler trace holds no device "
                                    "operation inside the window")
-        mem = dev.memory_stats() or {}
-        peak = int(mem.get("peak_bytes_in_use", 0))
+        peaks_per_device = [
+            int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in cell_devices]
+        peak = max(peaks_per_device)
         traces = (engine.tracer.recent()[n_traces0 - engine.tracer.n_traces:]
                   if trace and engine.tracer.n_traces > n_traces0 else [])
         store_rows = (check.store_rows_in_model_ids(model, store)
@@ -365,7 +415,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     log(f"generator lateness p95: "
         f"{1e3 * float(np.percentile(lateness, 95)) if lateness else 0.0:.3f}"
         f" ms over {len(lateness)} requests")
-    log(f"peak_bytes_in_use: {peak}")
+    log(f"peak_bytes_in_use: {peaks_per_device}")
     del engine, store
     t_ref = time.perf_counter()
     checks = check.check(model, reqlog, sched.setup_writes, store_rows)
@@ -382,7 +432,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         least_bytes=({t: least_bytes(model, t)
                       for t in {r.text for r in reqlog if r.kind == "read"}}
                      if trace else {}),
-        peaks=peaks)
+        peaks=peaks, phases=phased)
     if report is not None:
         answered = [r for r in reqlog if r.ok and r.t_done <= t_close]
         report.update(
@@ -393,7 +443,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             window_compiles=ctx.compiles_in_window,
             distinct_reads=len({r.text for r in reqlog if r.kind == "read"}),
             prepared_misses=(stats1["prepared_cache"]["misses"]
-                             - stats0["prepared_cache"]["misses"]))
+                             - stats0["prepared_cache"]["misses"]),
+            phases=phased)
     metrics = {}
     units = {m["name"]: m["unit"]
              for m in bench["end_to_end"] + bench["per_layer"]}
@@ -408,7 +459,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         "failed": sum(not r.ok for r in reqlog),
         "metrics": metrics,
         "device": {"platform": dev.platform, "kind": dev.device_kind,
-                   "count": len(devices), "memory_peak_bytes": peak},
+                   "count": len(devices), "memory_peak_bytes": peak,
+                   "memory_peak_bytes_per_device": peaks_per_device},
     }
     if trace and reduced is not None:
         result["device"]["busy_s"] = reduced.busy_s
@@ -425,6 +477,12 @@ def latencies_ms(ctx: Ctx, kind: str) -> list[float]:
     answered, from its send (closed loop) or its due time (open loop)."""
     return [1e3 * (r.t_done - r.t_from) for r in ctx.log
             if r.kind == kind and r.ok]
+
+
+def answered_reads(ctx: Ctx) -> int:
+    """Reads answered without an error inside the window."""
+    return sum(1 for r in ctx.log if r.kind == "read" and r.ok
+               and r.t_done <= ctx.t_close)
 
 
 def percentile(values, q: float):

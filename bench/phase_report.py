@@ -5,7 +5,7 @@ phase, its busy time by plan operator, and each read's latency by span.
         --seconds 45 [--out phases.json]
 
 Runs the cell as `bench/run.py --trace 1` does (bench/harness.py, on the
-chip), and reduces the same profiler trace a second time with
+chip), and reports the harness's reduction of that profiler trace by
 bench/phases.py, given the engine's `op_scopes()`. From the engine's
 spans it gives the mean per answered read of each span, beside the mean
 read latency the spans should add up to. Prints one JSON object as the
@@ -13,7 +13,7 @@ last line (and writes it to --out). The budget's `covers_read` is false,
 and a warning goes to stderr, where the spans add up to less than
 COVERS of the mean read. A program without phase annotations, scopes or
 the newer spans reads None or 0 where those would be. Exits 1 if the
-traced run's trace was never reduced here.
+harness returned no phase reduction.
 """
 import time
 
@@ -79,20 +79,8 @@ def main(argv=None) -> int:
     os.environ.setdefault("TPU_LOG_DIR",
                           os.path.join(tempfile.gettempdir(), "tpu_logs"))
     import harness
-    import phases
-    import xplane
 
     held: dict = {}
-    reduce_file = xplane.reduce_file
-
-    def reduce_twice(path, top=10):
-        planes = phases.load(path)
-        op_scopes = getattr(held["engine"], "op_scopes", None)
-        scopes = op_scopes() if op_scopes else None
-        held["phases"] = phases.reduce_planes(planes, scopes)
-        return xplane.reduce_planes(planes, top)
-
-    xplane.reduce_file = reduce_twice
     report: dict = {}
     try:
         result = harness.run(args.workload, args.seed, args.seconds, True,
@@ -102,17 +90,14 @@ def main(argv=None) -> int:
     except harness.NoChip as e:
         print(f"no result: {e}", file=sys.stderr)
         return 3
-    finally:
-        xplane.reduce_file = reduce_file
-    if "phases" not in held:
-        print("no result: the harness reduced the trace without "
-              "xplane.reduce_file, so the phases were never read",
-              file=sys.stderr)
+    if "phases" not in report:
+        print("no result: the harness returned no phase reduction, so the "
+              "phases were never read", file=sys.stderr)
         return 1
     tracer = held["engine"].tracer
     k = tracer.n_traces - held.get("n0", tracer.n_traces)
     traces = tracer.recent()[-k:] if k else []
-    ph = held.get("phases")
+    ph = report["phases"]
     out = {
         "workload": args.workload, "seed": args.seed,
         "correct": result["correct"], "failed": result["failed"],

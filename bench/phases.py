@@ -28,6 +28,11 @@ the stat `module`, came last before the module started on the device
 (the batcher waits on each dispatch before it launches the next), and
 has the same module name. Busy time, window and device choice are those
 of `xplane.reduce_planes`.
+
+On several chips, the exposed collective time is read on every chip:
+the seconds in which a collective op (all-to-all, all-gather,
+all-reduce, collective-permute, reduce-scatter, or the start or done of
+one) runs and no other op does, taken as the mean over the chips.
 """
 from __future__ import annotations
 
@@ -48,6 +53,8 @@ DISPATCH = ("prepare", "stage", "launch")
 PARTS = ("dispatch", "sync", "batch", "decode", "wait", "other", "none")
 NAME_CHARS = 120  # an op's name as reported: its HLO instruction's head
 _INSTR = re.compile(r"%?([\w.\-]+)")
+_COLLECTIVE = re.compile(
+    r"all-to-all|all-gather|all-reduce|collective-permute|reduce-scatter")
 
 
 @dataclasses.dataclass
@@ -66,6 +73,7 @@ class Phases:
     modules_known: int  # ... tied to an executable op_scopes() names
     modules_unscoped: int  # ... known, but compiled without scopes
     module_keys: list  # [[trace module name, op_scopes() key]], distinct
+    collective_exposed_s: float | None  # mean over chips; None: none ran
 
     def idle_share(self, part: str) -> float:
         return self.idle_s[part] / self.window_s
@@ -127,11 +135,14 @@ def reduce_planes(planes, scopes: dict | None = None,
     ran was compiled without them (a persistent-cache hit on a build that
     named no scopes): they would count its time as no operator's."""
     window, device, modules, launches = None, None, [], []
+    chips = []  # the ops of every device plane that has any
     threads = []  # per host line: {phase: [(start, end)]}
     for pname, lines in planes:
         if pname.startswith("/device:"):
             ops = [ev for lname, evs in lines if lname in OPS_LINES
                    for ev in evs]
+            if ops:
+                chips.append(ops)
             if ops and device is None:
                 device = ops
                 modules = [ev for lname, evs in lines
@@ -214,8 +225,43 @@ def reduce_planes(planes, scopes: dict | None = None,
         batcher_s={p: _total(iv) * 1e-9 for p, iv in b_iv.items()},
         decode_s=decode_s,
         gaps=gaps,
+        collective_exposed_s=exposed_collective_s(chips, w0, w1),
         **ops,
     )
+
+
+def _instruction(op_name: str) -> str:
+    m = _INSTR.match(op_name)
+    return m.group(1) if m else op_name
+
+
+def exposed_collective_s(chips, w0, w1) -> float | None:
+    """Mean over `chips` (each a list of (op name, start_ns, duration_ns))
+    of the seconds inside [w0, w1] in which a collective op runs on that
+    chip and no other op does. An op whose event contains a collective's
+    is its caller (a `while` or call around it, on a TPU), not work beside
+    it. None where no collective ran inside the window on any chip."""
+    exposed, seen = [], False
+    for ops in chips:
+        coll, other = [], []
+        for name, s, d in ops:
+            is_coll = _COLLECTIVE.search(_instruction(name))
+            (coll if is_coll else other).append((s, s + d))
+        c = np.asarray(coll, np.float64).reshape(-1, 2)
+        o = np.asarray(other, np.float64).reshape(-1, 2)
+        if len(c) and len(o):
+            c = c[np.argsort(c[:, 0], kind="stable")]
+            lo = np.searchsorted(c[:, 0], o[:, 0], side="left")
+            hi = np.searchsorted(c[:, 0], o[:, 1], side="right")
+            caller = np.zeros(len(o), bool)
+            for k in np.flatnonzero(hi > lo):
+                caller[k] = bool((c[lo[k]:hi[k], 1] <= o[k, 1]).any())
+            o = o[~caller]
+        c, o = _clip(c, w0, w1), _clip(o, w0, w1)
+        seen = seen or len(c) > 0
+        both = _total(_union(np.concatenate([c, o])))
+        exposed.append(both - _total(o))  # |C| - |C and O|
+    return float(np.mean(exposed)) * 1e-9 if seen else None
 
 
 def _outermost(device, w0, w1) -> list:
@@ -263,8 +309,7 @@ def _attribute(device, modules, launches, scopes, w0, w1) -> dict:
     for name, s, a, b in _outermost(device, w0, w1):
         i = int(np.searchsorted(starts, s, side="right")) - 1
         mod = mods[i][3] if i >= 0 and s < mods[i][1] else None
-        m = _INSTR.match(name)
-        instr = m.group(1) if m else name
+        instr = _instruction(name)
         scope = None
         if scopes is not None and mod in scopes:
             scope = scopes[mod].get(instr, "")

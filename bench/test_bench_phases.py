@@ -3,6 +3,7 @@ thread was doing, device time by plan operator, the new per-read metric
 readers and the latency budget; checked on synthetic planes and on a
 trace recorded on the chip. The earlier reduction reads the chip's small
 trace as it always has."""
+import dataclasses
 import json
 import os
 import types
@@ -258,9 +259,9 @@ def test_budget_flags_spans_that_leave_the_read_uncovered():
 
 
 def test_report_fails_when_the_trace_was_never_reduced(monkeypatch, capsys):
-    """A harness that reduces the trace some other way than through
-    xplane.reduce_file leaves the phases unread: the report says so and
-    exits 1 instead of printing null phases."""
+    """A harness run that hands back no phase reduction leaves the phases
+    unread: the report says so and exits 1 instead of printing null
+    phases."""
     import harness
 
     def run(*args, **kw):
@@ -284,3 +285,83 @@ def test_new_metrics_are_declared_for_the_complex_cell():
         assert (m["source"], m["moves"], m["workloads"]) == (
             "program_span", "read_p95_ms", ["lubm20.complex"])
         assert os.path.exists(os.path.join(HERE, "metrics", name + ".py"))
+
+
+# -------------------------------------------- several chips
+
+
+def _ops(*evs):
+    return [(name, a * MS, (b - a) * MS) for name, a, b in evs]
+
+
+def test_exposed_collectives_mean_over_chips():
+    chip0 = _ops(("%all-to-all.3 = s32[8] all-to-all(%x)", 10, 30),
+                 ("%fusion.1 = s32[8] fusion(%y)", 20, 40),
+                 # a loop around a collective is its caller, not work
+                 # beside it; the loop body's fusion is
+                 ("%while.2 = (s32[]) while(%t)", 50, 80),
+                 ("%all-gather-done.4 = s32[8] all-gather-done(%s)", 55, 65),
+                 ("%fusion.5 = s32[8] fusion(%z)", 60, 70))
+    chip1 = _ops(("%fusion.7 = s32[8] fusion(%p)", 0, 90),
+                 # runs past the window's close at 100 ms
+                 ("%collective-permute-start.1 = s32[8] "
+                  "collective-permute-start(%q)", 95, 110),
+                 ("%fusion.8 = s32[8] fusion(%r)", 96, 97))
+    # chip 0: [10,20] and [55,60]; chip 1: [95,96] and [97,100]
+    got = phases.exposed_collective_s([chip0, chip1], 0.0, 100 * MS)
+    assert got == pytest.approx((0.015 + 0.004) / 2)
+    # compute alone exposes nothing, and nor does a collective that
+    # compute beside it covers throughout
+    assert phases.exposed_collective_s(
+        [_ops(("%fusion.1 = f", 0, 50))], 0.0, 100 * MS) is None
+    assert phases.exposed_collective_s(
+        [_ops(("%fusion.1 = f", 0, 15), ("%fusion.3 = f", 15, 50),
+              ("%all-reduce.2 = a", 10, 20))], 0.0, 100 * MS) == 0.0
+
+
+def test_exposed_collectives_through_the_phase_reduction():
+    planes = _planes()
+    assert phases.reduce_planes(planes, SCOPES).collective_exposed_s is None
+    chip1 = ("/device:TPU:1", [("XLA Ops", _ops(
+        ("%all-to-all.1 = s32[8] all-to-all(%x)", 10, 30)))])
+    r = phases.reduce_planes(planes + [chip1], SCOPES)
+    assert r.collective_exposed_s == pytest.approx(0.020 / 2)
+    # the serving-phase split still reads the first chip
+    assert r.busy_s == pytest.approx(0.027)
+
+
+def test_phase_metrics_read_off_the_recorded_trace():
+    """The three per-layer metrics the harness reads from its phase
+    reduction, on the chip's recorded trace: idle while the batcher
+    dispatches, idle while rows decode, join device time per read."""
+    data = os.path.join(HERE, "testdata")
+    with open(os.path.join(data, "phases.scopes.json")) as f:
+        scopes = json.load(f)
+    ph = phases.reduce_planes(phases.load(os.path.join(data,
+                                                       "phases.xplane.pb")),
+                              scopes)
+    read = types.SimpleNamespace(kind="read", ok=True, t_done=1.0)
+    late = types.SimpleNamespace(kind="read", ok=True, t_done=3.0)
+    ctx = types.SimpleNamespace(phases=ph, log=[read] * 4 + [late],
+                                t_close=2.0)
+    window = 0.077687676
+    assert ph.window_s == pytest.approx(window)
+    assert _reader("idle_dispatch_share.complex")(ctx) == pytest.approx(
+        100 * 0.006226838 / window)
+    assert _reader("idle_decode_share.complex")(ctx) == pytest.approx(
+        100 * 0.015548545 / window)
+    assert _reader("join_device_ms_per_query.complex")(ctx) == (
+        pytest.approx(1e3 * 0.004776413 / 4))
+    # one chip, no collective: the sharded reader finds nothing to read
+    assert _reader("collective_exposed_ms_per_query.sharded")(ctx) is None
+    # an untraced run, or a module compiled without scopes, reads nothing
+    assert _reader("idle_dispatch_share.complex")(
+        types.SimpleNamespace(phases=None)) is None
+    ctx.phases = dataclasses.replace(ph, join_s=None)
+    assert _reader("join_device_ms_per_query.complex")(ctx) is None
+    with open(os.path.join(HERE, "..", "BENCHMARK.json")) as f:
+        per_layer = {m["name"]: m for m in json.load(f)["per_layer"]}
+    for name in ("idle_dispatch_share.complex", "idle_decode_share.complex",
+                 "join_device_ms_per_query.complex"):
+        assert per_layer[name]["workloads"] == ["lubm20.complex"]
+        assert per_layer[name]["moves"] == "queries_per_s"

@@ -3,9 +3,12 @@ inside the harness's window, idle gaps are named by what covers most of
 them on the host; checked on synthetic planes and on a trace recorded on
 the chip."""
 import os
+import types
 
 import pytest
 
+import harness
+import phases
 import xplane
 
 MS = 1e6  # ns
@@ -57,3 +60,40 @@ def test_recorded_tpu_trace():
     assert "sort" in r.device_ops[0][0]
     long_gaps = [name for name, s in r.idle_gaps if s >= 0.015]
     assert long_gaps == [xplane.NO_REQUEST] * 3
+
+
+def _roofline_ctx(device, moved):
+    read = types.SimpleNamespace(kind="read", ok=True, t_done=1.0, text="q")
+    return types.SimpleNamespace(
+        device=device, device_kind="TPU v5 lite", log=[read], t_close=2.0,
+        least_bytes={"q": moved},
+        peaks={"TPU v5 lite": {"hbm_bytes_per_s": 819e9}})
+
+
+def test_one_chip_readings_of_the_recorded_trace_are_as_before():
+    """The harness now loads the planes once for both reductions; on the
+    chip's small trace program_roofline and device_idle_share.complex
+    read what the one-chip formulas read before."""
+    path = os.path.join(os.path.dirname(__file__), "testdata",
+                        "small.xplane.pb")
+    r = xplane.reduce_planes(phases.load(path))
+    assert r == xplane.reduce_file(path)
+    ctx = _roofline_ctx(r, 10**6)
+    assert harness.read_metric("program_roofline", ctx) == pytest.approx(
+        100.0 * (10**6 / 819e9) / 0.002775731, rel=1e-12)
+    assert harness.read_metric("device_idle_share.complex", ctx) == (
+        pytest.approx(100.0 * (1 - 0.002775731 / 0.068427206), rel=1e-12))
+
+
+def test_roofline_over_four_chips_counts_every_chips_peak():
+    """Busy time is a mean over the chips, so the bytes the reads need are
+    held against four chips' peak: 90% of it reads 90%, not 360%."""
+    planes = [("/host:CPU", [("python", [("window", 0.0, 100 * MS)])])]
+    for k in range(4):
+        planes.append((f"/device:TPU:{k}",
+                       [("XLA Ops", [("%sort.1 = s", (10 + k) * MS, 10 * MS)])]))
+    r = xplane.reduce_planes(planes)
+    assert (r.n_devices, r.busy_s) == (4, pytest.approx(0.010))
+    moved = 0.9 * 4 * 819e9 * 0.010
+    got = harness.read_metric("program_roofline", _roofline_ctx(r, moved))
+    assert got == pytest.approx(90.0) and got <= 100.0

@@ -106,7 +106,7 @@ def reduce_planes(planes, top: int = 10) -> Reduced | None:
     host_iv = {k: _union(np.asarray(v, np.float64).reshape(-1, 2))
                for k, v in host.items()}
     any_iv = _union(np.concatenate([iv for iv in host_iv.values()]))
-    # idle gaps of the first device (one chip per cell here)
+    # idle gaps of the first device; busy time above is a mean over all
     u = unions[0]
     edges = np.concatenate([[w0], u.ravel(), [w1]]).reshape(-1, 2)
     gaps = [(a, b) for a, b in edges if b > a]
